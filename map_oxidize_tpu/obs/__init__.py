@@ -127,10 +127,10 @@ class Obs:
     """Per-job observability bundle: one registry, one tracer, and an
     optional heartbeat, threaded through driver -> engine -> spill layers.
 
-    Always constructed (metrics were always-on in the seed too); the
-    tracer is enabled only when the job asked for a trace, and its
-    disabled spans are a shared no-op object, so the hot-path cost of an
-    un-traced run is one attribute check per span site.
+    Always constructed (metrics were always-on in the seed too).  The
+    tracer's spans always feed the registry (``span/<name>_s`` and the
+    phase clocks, :meth:`MetricsRegistry.record_span`); it keeps the
+    Chrome event list only when the job asked for a trace.
     """
 
     registry: MetricsRegistry
@@ -186,9 +186,13 @@ class Obs:
     #: then, and always None when ``config.data_audit`` is off
     dataplane: "object | None" = None
     dataplane_enabled: bool = True
-    #: first-phase latch: Obs.phase stamps ``attrib/setup_ms`` (wall
-    #: from Obs creation to the first phase span) exactly once
-    _setup_stamped: bool = False
+    #: the ``job/setup`` span: opened by :meth:`from_config`, closed by
+    #: the first phase (or ``finish``) — engine, plan and audit bring-up
+    _setup_span: "Span | None" = None
+
+    def __post_init__(self):
+        if self.tracer.sink is None:
+            self.tracer.sink = self.registry.record_span
 
     @classmethod
     def from_config(cls, config, process: int = 0,
@@ -242,6 +246,7 @@ class Obs:
                   process=process, n_processes=n_processes,
                   dataplane_enabled=bool(
                       getattr(config, "data_audit", True)))
+        obs._setup_span = tracer.span("job/setup").__enter__()
         # the XLA program observatory is always-on: compile counts, costs
         # and dispatch gaps accrue in the process-global ledger; the job
         # deltas against this baseline at finish (obs/compile.py)
@@ -384,37 +389,32 @@ class Obs:
         if self.cancel_event.is_set():
             raise JobCancelled(self.cancel_reason or "cancelled")
 
+    def end_setup(self) -> None:
+        """Close the ``job/setup`` span (Obs creation to the first phase:
+        config, engine, backend and audit bring-up — the attribution
+        ledger's ``setup`` bucket source).  Idempotent."""
+        sp, self._setup_span = self._setup_span, None
+        if sp is not None:
+            sp.__exit__(None, None, None)
+
     @contextlib.contextmanager
     def phase(self, name: str, **attrs):
-        """One job phase: wall-clocked in the registry, a top-level span in
-        the trace, the heartbeat's current phase label, and a host-RSS
-        watermark sample on exit (phase boundaries are where residency
-        peaks: finalize fetches, sort buffers, write staging).  Also a
-        cancellation point (:meth:`poll_cancel`)."""
+        """One job phase: a top-level span (whose sink keeps the
+        ``time/<name>_s`` phase clock), the heartbeat's current phase
+        label, and a host-RSS watermark sample on exit (phase boundaries
+        are where residency peaks: finalize fetches, sort buffers, write
+        staging).  Also a cancellation point (:meth:`poll_cancel`)."""
         self.poll_cancel()
-        if not self._setup_stamped:
-            # the attribution ledger's ``setup`` bucket source: Obs
-            # creation to the first phase span (config/engine/backend
-            # bring-up).  Deliberately NOT named attrib/setup_ms — the
-            # published bucket gauge owns that name, and a shared name
-            # would feed the published value back into the next compute
-            self._setup_stamped = True
-            import time as _time
-
-            self.registry.set(
-                "attrib/pre_phase_ms",
-                round(max(_time.time() - self.tracer.wall_start, 0.0)
-                      * 1e3, 3))
+        self.end_setup()
         if self.heartbeat is not None:
             self.heartbeat.set_phase(name)
         prev, self.current_phase = self.current_phase, name
         with self.tracer.span(f"phase/{name}", **attrs):
-            with self.registry.phase(name):
-                try:
-                    yield
-                finally:
-                    self.current_phase = prev
-                    sample_host_memory(self.registry)
+            try:
+                yield
+            finally:
+                self.current_phase = prev
+                sample_host_memory(self.registry)
 
     def feed_span(self, **attrs) -> "Span":
         """Span for one mapped block's engine feed (the per-block latency
@@ -515,13 +515,12 @@ class Obs:
             # evidence, never a reason to fail a finished job
             get_logger(__name__).warning("calibration merge failed: %s", e)
 
-    def finish(self, config, workload: str | None = None
-               ) -> tuple[dict, list | None]:
-        """End-of-job hook: final memory watermarks, the xprof export,
-        flag-driven file exports (version/config-hash stamped), the
-        optional ledger append, and the ``(summary, trace_events)`` pair
-        the result carries.  ``trace_events`` is None when tracing was
-        off."""
+    def _conclude(self, config, workload: str | None):
+        """The end-of-job analysis ``finish`` runs under its ``job/finish``
+        span: stop the live plane, close the xprof window, attribute the
+        wall, score the plan, merge calibration, publish the audit, take
+        the final memory watermarks.  Returns ``(xprof_report,
+        attrib_doc, critpath_doc, data_doc)``."""
         self.stop_live()
         xprof_report = self.finish_xprof()
         # the end-of-job wall attribution: buckets + unattributed
@@ -576,6 +575,19 @@ class Obs:
         sample_device_memory(self.registry)
         if self.heartbeat is not None:
             self.heartbeat.final_beat()
+        return xprof_report, attrib_doc, critpath_doc, data_doc
+
+    def finish(self, config, workload: str | None = None
+               ) -> tuple[dict, list | None]:
+        """End-of-job hook: the analysis of :meth:`_conclude` under the
+        ``job/finish`` span, then flag-driven file exports (version/
+        config-hash stamped), the optional ledger append, and the
+        ``(summary, trace_events)`` pair the result carries.
+        ``trace_events`` is None when tracing was off."""
+        self.end_setup()
+        with self.tracer.span("job/finish"):
+            xprof_report, attrib_doc, critpath_doc, data_doc = (
+                self._conclude(config, workload))
         meta = self.stamp(config, workload)
         if config.metrics_out:
             doc = dict(self.registry.to_dict(), meta=meta)

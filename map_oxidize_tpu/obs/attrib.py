@@ -28,7 +28,7 @@ Bucket definitions (ms on the job's critical path):
 
 ``setup``
     ``Obs`` creation to the first phase span: config/engine/backend
-    bring-up (``attrib/setup_ms``, stamped by the first ``Obs.phase``).
+    bring-up (the ``job/setup`` span, ``span/job/setup_s``).
 ``host_produce``
     Host production work that ran ON the critical path: the ``split``
     chunk-planning phase plus explicitly measured inline produce (the
@@ -139,7 +139,6 @@ def compute(obs, programs: dict | None = None,
     reg = obs.registry
     with reg._lock:
         counters = dict(reg.counters)
-        gauges = dict(reg.gauges)
         phases = dict(reg.phases)
         gap_ms = _hist_total_ms(reg, "device/dispatch_gap_ms")
         compute_ms = _hist_total_ms(reg, "device/compute_ms")
@@ -159,12 +158,12 @@ def compute(obs, programs: dict | None = None,
     feed_wait = float(counters.get("pipeline/feed_wait_ms", 0.0))
 
     buckets = {
-        # pre-first-phase wall (the Obs.phase stamp) plus in-phase
+        # pre-first-phase wall (the job/setup span) plus in-phase
         # framework bring-up measured at known choke points (mesh/
         # backend init inside a streamed fit).  The SOURCES live under
         # their own names; the published attrib/setup_ms gauge is this
         # bucket's output and must never feed back in
-        "setup": (float(gauges.get("attrib/pre_phase_ms", 0.0))
+        "setup": (float(counters.get("span/job/setup_s", 0.0)) * 1e3
                   + float(counters.get("attrib/init_ms", 0.0))),
         "host_produce": (
             float(counters.get("attrib/probe_ms", 0.0))

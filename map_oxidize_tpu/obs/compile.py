@@ -37,6 +37,7 @@ from __future__ import annotations
 import threading
 import time
 
+from map_oxidize_tpu.obs.trace import NULL_SPAN
 from map_oxidize_tpu.utils.logging import get_logger
 
 _log = get_logger(__name__)
@@ -528,9 +529,13 @@ class ObservedJit:
             base = jbase.get(self._name)
             n = stats.dispatches - (base[3] if base else 0) + 1
         if n <= 1 or n % self._sample_every == 0 or compiled:
+            # the host blocked on the device: its own span in the job
+            # (device_wait_s reads it), and the ledger's compute sample
             t1 = time.perf_counter()
             try:
-                jax.block_until_ready(out)
+                with (_obs.tracer.span("xprof/ready_wait", program=self._name)
+                      if _obs is not None else NULL_SPAN):
+                    jax.block_until_ready(out)
                 ready_ms = (time.perf_counter() - t1) * 1e3
             except Exception:
                 ready_ms = None

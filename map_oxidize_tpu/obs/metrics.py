@@ -133,6 +133,30 @@ def _round6(v):
     return None if v is None else round(v, 6)
 
 
+#: spans whose durations also land in a histogram, in ms, under the name
+#: its readers (obs/attrib.py, obs/xprof.py, the ledger) know
+SPAN_HISTOGRAMS = {
+    "engine/feed_block": "feed_block_ms",
+    "engine/flush": "engine/flush_ms",
+    "engine/health_sync": "engine/health_sync_ms",
+    "engine/growth_sync": "engine/growth_sync_ms",
+}
+
+
+def _span_alias(name: str) -> tuple[str | None, str | None]:
+    """``(kind, key)`` of the older key a span also writes, if any."""
+    if name.startswith("phase/"):
+        return "phase", name[len("phase/"):]
+    if name.endswith("/feed_wait"):
+        # every prefetcher's consumer stall (map/, push/, kmeans/map/...)
+        return "counter_ms", "pipeline/feed_wait_ms"
+    if name.endswith("/produce"):
+        return "counter_ms", "pipeline/produce_ms"
+    if name in SPAN_HISTOGRAMS:
+        return "hist_ms", SPAN_HISTOGRAMS[name]
+    return None, None
+
+
 class MetricsRegistry:
     """Thread-safe registry of phases, counters, gauges, and histograms.
 
@@ -157,6 +181,8 @@ class MetricsRegistry:
         #: name (obs/serve.py's exporter owns the population logic)
         self._prom_names: dict = {}
         self._prom_used: set = set()
+        #: span name -> its registry keys (:meth:`record_span`)
+        self._span_keys: dict[str, tuple] = {}
         self._lock = threading.Lock()
 
     # --- seed-compatible surface -----------------------------------------
@@ -201,6 +227,36 @@ class MetricsRegistry:
             if h is None:
                 h = self.histograms[name] = Histogram(buckets=buckets)
             h.observe(value)
+
+    def record_span(self, name: str, dur_s: float, self_s: float) -> None:
+        """The span sink (:class:`~map_oxidize_tpu.obs.trace.Tracer`
+        ``sink``): every closed span adds its duration, self time and
+        count under ``span/<name>_s``, ``span/<name>_self_s`` and
+        ``span/<name>_n``, and writes the keys older readers know for the
+        same region: ``phase/<p>`` the ``time/<p>_s`` phase clock,
+        ``<prefetcher>/produce`` and ``/feed_wait`` the ``pipeline/*_ms``
+        counters, and the spans of :data:`SPAN_HISTOGRAMS` their
+        histograms."""
+        keys = self._span_keys.get(name)
+        if keys is None:
+            keys = self._span_keys[name] = (
+                f"span/{name}_s", f"span/{name}_self_s", f"span/{name}_n",
+                _span_alias(name))
+        dur_key, self_key, n_key, (kind, alias) = keys
+        with self._lock:
+            c = self.counters
+            c[dur_key] = c.get(dur_key, 0.0) + dur_s
+            c[self_key] = c.get(self_key, 0.0) + self_s
+            c[n_key] = c.get(n_key, 0) + 1
+            if kind == "phase":
+                self.phases[alias] = self.phases.get(alias, 0.0) + dur_s
+            elif kind == "counter_ms":
+                c[alias] = c.get(alias, 0.0) + dur_s * 1e3
+            elif kind == "hist_ms":
+                h = self.histograms.get(alias)
+                if h is None:
+                    h = self.histograms[alias] = Histogram()
+                h.observe(dur_s * 1e3)
 
     @contextlib.contextmanager
     def timer(self, name: str):

@@ -368,14 +368,13 @@ def build_status(obs, config, workload: str | None = None) -> dict:
               if k.startswith("data/")}
         if dg:
             doc["data"] = dg
-    # open span stacks (what the job is doing RIGHT NOW), when tracing
-    if obs.tracer.enabled:
-        stacks = []
-        with obs.tracer._lock:
-            for _tid, stack in obs.tracer._stacks:
-                if stack:
-                    stacks.append(" > ".join(s.name for s in stack))
-        doc["open_spans"] = stacks
+    # open span stacks (what the job is doing RIGHT NOW)
+    stacks = []
+    with obs.tracer._lock:
+        for _tid, stack in obs.tracer._stacks:
+            if stack:
+                stacks.append(" > ".join(s.name for s in stack))
+    doc["open_spans"] = stacks
     if obs.n_processes > 1:
         doc["process"] = obs.process
         doc["n_processes"] = obs.n_processes

@@ -9,9 +9,23 @@ event list records parent depth, so the JSONL export preserves nesting
 explicitly while the Chrome export gets it for free (Perfetto nests
 same-tid events by time containment).
 
-Disabled tracers hand out one shared no-op span object; the per-site cost
-of an un-traced run is a single attribute check, which is how the job
-keeps its <2% flags-off overhead budget.
+Every span also feeds a **sink** whether or not the event list is kept:
+an ``Obs`` bundle wires its registry's :meth:`record_span
+<map_oxidize_tpu.obs.metrics.MetricsRegistry.record_span>` here, so each
+span adds its duration, its self time (the duration minus the part its
+same-thread child spans cover) and its count to the job's metrics under
+``span/<name>_s``, ``span/<name>_self_s`` and ``span/<name>_n``.  The
+Chrome event list is kept only when the tracer is ``enabled``
+(``--trace-out``).  A tracer with neither a sink nor an event list (no
+``Obs`` behind it) hands out one shared no-op span.  The cost of a span
+is two clock reads, a stack push/pop and one locked dict add.
+
+Each span also opens a ``jax.profiler.TraceAnnotation`` of the same name
+(once the process has imported jax), so any profiler capture with the
+host tracer at level 1 or above holds the program's spans on the
+profiler's own clock, one line per thread, beside the device timeline.
+With no profiler session active the annotation costs well under a
+microsecond.
 
 Causally-paired spans carry sequence tags in their args — ``round=<k>``
 on the distributed drivers' lockstep flag/exchange spans (round *k* is
@@ -31,13 +45,15 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 
 
 class _NullSpan:
-    """Shared do-nothing span for disabled tracers (and a safe default for
-    engines whose driver never attached an ``Obs``)."""
+    """Shared do-nothing span for tracers with no sink and no event list
+    (and a safe default for engines whose driver never attached an
+    ``Obs``)."""
 
     __slots__ = ()
 
@@ -54,19 +70,31 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+def _annotation_type():
+    """``jax.profiler.TraceAnnotation`` once the process has imported
+    jax, else None: no profiler session can exist before, and a span must
+    not pay jax's import."""
+    profiler = sys.modules.get("jax.profiler")
+    return getattr(profiler, "TraceAnnotation", None)
+
+
 class Span:
     """One open timed region.  Use as a context manager; the end time is
     recorded in ``__exit__`` even when the body raises, and an exception
     is annotated on the event (``error`` attribute) rather than losing
-    the span."""
+    the span.  After ``__exit__``, ``dur`` holds its seconds."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth", "_done")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth", "_done",
+                 "_stack", "_child", "_ann", "dur")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
         self._done = False
+        self._child = 0.0
+        self._ann = None
+        self.dur = 0.0
 
     def set(self, **attrs) -> "Span":
         """Attach/overwrite attributes while the span is open."""
@@ -74,15 +102,24 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
-        self._t0 = self._tracer._clock()
-        stack = self._tracer._stack()
+        # the OWNING thread's stack, kept on the span: a span closed from
+        # another thread (job/setup, closed by the first phase) must pop
+        # its own stack, never the closing thread's
+        stack = self._stack = self._tracer._stack()
         self._depth = len(stack)
         stack.append(self)
+        ann = _annotation_type()
+        if ann is not None:
+            self._ann = ann(self.name)
+            self._ann.__enter__()
+        self._t0 = self._tracer._clock()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = self._tracer._clock()
-        stack = self._tracer._stack()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        stack = self._stack
         # exception safety: pop through to this span even if a child span
         # leaked (its __exit__ never ran because of a lower-level crash)
         while stack and stack[-1] is not self:
@@ -94,10 +131,13 @@ class Span:
             # another thread force-closed it); don't record it twice
             return False
         self._done = True
+        self.dur = t1 - self._t0
+        if stack:
+            # the parent on the same thread: its self time excludes ours
+            stack[-1]._child += self.dur
         if exc_type is not None:
             self.attrs["error"] = f"{exc_type.__name__}: {exc}"
-        self._tracer._record(self.name, self._t0, t1, self._depth,
-                             self.attrs)
+        self._tracer._finish(self, t1)
         return False
 
 
@@ -108,10 +148,16 @@ class Tracer:
     is thread-local.  Timestamps are microseconds since tracer creation
     (``perf_counter``-based, so durations are monotonic and immune to
     wall-clock steps).
+
+    ``enabled`` keeps the event list; ``sink(name, dur_s, self_s)``, when
+    set, is called for every closed span either way (``Obs`` sets it to
+    its registry's ``record_span``).
     """
 
-    def __init__(self, enabled: bool = True, clock=time.perf_counter):
+    def __init__(self, enabled: bool = True, clock=time.perf_counter,
+                 sink=None):
         self.enabled = enabled
+        self.sink = sink
         self._clock = clock
         self._epoch = clock()
         #: wall-clock instant of the epoch — the cross-process alignment
@@ -147,9 +193,7 @@ class Tracer:
         a truncated timeline instead of losing the phases the crash
         interrupted.  Spans closed here are marked done, so a thread
         that later unwinds its ``with`` block does not record a
-        duplicate."""
-        if not self.enabled:
-            return 0
+        duplicate.  The sink gets each one's time up to now."""
         now = self._clock()
         with self._lock:
             stacks = [(tid, list(s)) for tid, s in self._stacks]
@@ -159,18 +203,22 @@ class Tracer:
         for tid, stack in stacks:
             for depth, span in enumerate(stack):
                 span._done = True
-                attrs = dict(span.attrs, unfinished=True)
-                if error is not None:
-                    attrs.setdefault("error", error)
-                self._record(span.name, span._t0, now, depth, attrs,
-                             tid=tid)
+                if self.sink is not None:
+                    dur = now - span._t0
+                    self.sink(span.name, dur, dur - span._child)
+                if self.enabled:
+                    attrs = dict(span.attrs, unfinished=True)
+                    if error is not None:
+                        attrs.setdefault("error", error)
+                    self._record(span.name, span._t0, now, depth, attrs,
+                                 tid=tid)
                 closed += 1
         return closed
 
     def span(self, name: str, **attrs):
         """Open a named span (context manager).  Returns the shared no-op
-        span when disabled."""
-        if not self.enabled:
+        span when the tracer has neither a sink nor an event list."""
+        if not self.enabled and self.sink is None:
             return NULL_SPAN
         return Span(self, name, attrs)
 
@@ -188,6 +236,13 @@ class Tracer:
                 "depth": len(self._stack()),
                 "args": attrs,
             })
+
+    def _finish(self, span: Span, t1: float) -> None:
+        """A span closed: feed the sink, keep the event when enabled."""
+        if self.sink is not None:
+            self.sink(span.name, span.dur, span.dur - span._child)
+        if self.enabled:
+            self._record(span.name, span._t0, t1, span._depth, span.attrs)
 
     def _record(self, name: str, t0: float, t1: float, depth: int,
                 attrs: dict, tid: int | None = None) -> None:

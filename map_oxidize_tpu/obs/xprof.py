@@ -364,13 +364,11 @@ class DeviceSampler:
         self.stall_warnings += 1
         tracer = self.obs.tracer
         spans = []
-        if tracer.enabled:
-            with tracer._lock:
-                for _tid, stack in tracer._stacks:
-                    if stack:
-                        spans.append(" > ".join(s.name for s in stack))
-        open_s = "; ".join(spans) if spans else "(no trace: run with " \
-                                                "--trace-out for span names)"
+        with tracer._lock:
+            for _tid, stack in tracer._stacks:
+                if stack:
+                    spans.append(" > ".join(s.name for s in stack))
+        open_s = "; ".join(spans) if spans else "(none)"
         line = (f"[stalled] no chunk completed in {elapsed:.1f}s "
                 f"(median {med:.2f}s, factor {self.stall_factor:g}); "
                 f"open spans: {open_s}")

@@ -1548,6 +1548,7 @@ def finish_distributed_obs(obs: Obs, config: JobConfig, workload: str
 
     from map_oxidize_tpu.obs import attrib as _attrib
 
+    obs.end_setup()
     obs.stop_live()
     xprof_report = obs.finish_xprof()
     # the end-of-job wall attribution, same as Obs.finish: each

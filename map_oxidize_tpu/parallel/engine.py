@@ -109,8 +109,10 @@ class ShardedReduceEngine(StreamingEngineBase):
     def _apply_grow(self, new_cap: int) -> None:
         self._acc = list(self._grow(*self._acc, new_cap - self.capacity))
 
-    def _merge_batch(self, padded) -> None:
-        batch = jax.device_put(padded, self._sharding)
+    def _put(self, padded):
+        return jax.device_put(padded, self._sharding)
+
+    def _merge_batch(self, batch) -> None:
         self.feed_device(*batch, count_rows=False)
 
     def feed_device(self, hi, lo, vals, count_rows: bool = True) -> None:

@@ -148,7 +148,6 @@ def _feed_records(config: JobConfig, obs: Obs, engine, corpora) -> tuple:
     under the pipeline wrapper.  Returns ``(records, n_chunks)``."""
     from map_oxidize_tpu.workloads.sort import iter_record_chunks
 
-    metrics = obs.registry
     records = 0
     n_chunks = 0
     rows_per_chunk = max(1, config.chunk_bytes // 16)
@@ -173,10 +172,8 @@ def _feed_records(config: JobConfig, obs: Obs, engine, corpora) -> tuple:
                                    obs, name="map"):
         records += out.records_in
         n_chunks += 1
-        t0 = time.perf_counter()
         with obs.feed_span(rows=len(out)):
             engine.feed(out)
-        metrics.observe("feed_block_ms", (time.perf_counter() - t0) * 1e3)
         if obs.heartbeat is not None:
             obs.heartbeat.update(rows=out.records_in, bytes_done=next_off)
     return records, n_chunks

@@ -378,34 +378,36 @@ def _run_wordcount_body(config: JobConfig, obs: Obs, mapper: Mapper,
         dictionary = HashDictionary()
     records_in = 0
     n_chunks = 0
+    span = obs.tracer.span
 
     def _ingest(out, next_off: int | None = None) -> None:
         nonlocal records_in, n_chunks
-        dictionary.update(out.dictionary)
+        with span("feed/dictionary"):
+            dictionary.update(out.dictionary)
+            if mapper.keys_have_dictionary:
+                # the dictionary covers every key fed so far, so its size
+                # bounds distinct keys — growth needs no device sync.
+                # upper_bound self-tightens with an amortized flush when
+                # pending deltas could be duplicate-dominated (see
+                # HashDictionary.upper_bound).
+                engine.hint_total_keys(dictionary.upper_bound())
         records_in += out.records_in
         n_chunks += 1
         if dp is not None and len(out):
             from map_oxidize_tpu.obs.dataplane import map_output_rows
 
-            rows = map_output_rows(out, pairs=False)
-            if rows is not None:  # scalar fold rows only (not k-means)
-                dp.record_fold_in(*rows)
+            with span("audit/fold_in"):
+                rows = map_output_rows(out, pairs=False)
+                if rows is not None:  # scalar fold rows only (not k-means)
+                    dp.record_fold_in(*rows)
         if do_combine and len(out):
             # map-side combine AFTER the audit digested the raw rows:
             # the weighted checksum is sum-combine-invariant, so the
             # conservation verdict is unchanged while the feed shrinks
             out, c_in, c_out = combine_map_output(out, reducer.combine)
             record_push_combine(obs, c_in, c_out)
-        if mapper.keys_have_dictionary:
-            # the dictionary covers every key fed so far, so its size bounds
-            # distinct keys — growth needs no device sync.  upper_bound
-            # self-tightens with an amortized flush when pending deltas
-            # could be duplicate-dominated (see HashDictionary.upper_bound).
-            engine.hint_total_keys(dictionary.upper_bound())
-        t0 = time.perf_counter()
         with obs.feed_span(rows=len(out)):
             engine.feed(out)
-        metrics.observe("feed_block_ms", (time.perf_counter() - t0) * 1e3)
         if obs.heartbeat is not None:
             # one update carrying BOTH the rows and the chunk's end offset:
             # a beat fired here must not read a percent that lags the rows
@@ -504,11 +506,12 @@ def _run_wordcount_body(config: JobConfig, obs: Obs, mapper: Mapper,
     # for count-shaped sum workloads — a min/max monoid or a sum of
     # measurements has no such identity (conserves=False skips it).
     if dp is not None:
-        dp.set_records_in(records_in)
-        dp.record_fold_out(counts._k64, counts._vals)
-        dp.resolve_hot_keys(dictionary.lookup)
-        dp.check_fold()
-        dp.check_total(counts.total())
+        with span("audit/fold_out"):
+            dp.set_records_in(records_in)
+            dp.record_fold_out(counts._k64, counts._vals)
+            dp.resolve_hot_keys(dictionary.lookup)
+            dp.check_fold()
+            dp.check_total(counts.total())
     elif (reducer.combine == "sum"
           and getattr(mapper, "conserves_counts", True)):
         # legacy global check — the audit's fallback when disabled
@@ -625,11 +628,10 @@ def _run_inverted_index_body(config: JobConfig, obs: Obs
         if dp is not None and len(out):
             from map_oxidize_tpu.obs.dataplane import map_output_rows
 
-            dp.record_pairs_in(*map_output_rows(out, pairs=True))
-        t0 = time.perf_counter()
+            with obs.tracer.span("audit/fold_in"):
+                dp.record_pairs_in(*map_output_rows(out, pairs=True))
         with obs.feed_span(rows=len(out)):
             engine.feed(out)
-        metrics.observe("feed_block_ms", (time.perf_counter() - t0) * 1e3)
         if obs.heartbeat is not None:
             obs.heartbeat.update(rows=out.records_in, bytes_done=next_off)
 
@@ -1193,16 +1195,14 @@ def _run_distinct_body(config: JobConfig, obs: Obs) -> DistinctResult:
         nonlocal records_in, n_chunks
         records_in += out.records_in
         n_chunks += 1
-        t0 = time.perf_counter()
-        if engine is not None:
-            with obs.feed_span(rows=len(out)):
+        with obs.feed_span(rows=len(out)):
+            if engine is not None:
                 engine.feed(out)
-        else:
-            # lo is flatnonzero output — unique per chunk, so fancy-index
-            # max is exact (and ~10x ufunc.at)
-            idx = out.lo.astype(np.int64)
-            host_regs[idx] = np.maximum(host_regs[idx], out.values)
-        metrics.observe("feed_block_ms", (time.perf_counter() - t0) * 1e3)
+            else:
+                # lo is flatnonzero output — unique per chunk, so
+                # fancy-index max is exact (and ~10x ufunc.at)
+                idx = out.lo.astype(np.int64)
+                host_regs[idx] = np.maximum(host_regs[idx], out.values)
         if obs.heartbeat is not None:
             obs.heartbeat.update(rows=out.records_in, bytes_done=next_off)
 

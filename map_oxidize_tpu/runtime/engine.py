@@ -33,7 +33,6 @@ periodically and raise rather than silently dropping keys.
 from __future__ import annotations
 
 import abc
-import contextlib
 import time
 from functools import partial
 
@@ -44,6 +43,7 @@ import jax.numpy as jnp
 from map_oxidize_tpu.api import MapOutput, Reducer
 from map_oxidize_tpu.config import JobConfig
 from map_oxidize_tpu.obs.compile import observed_jit
+from map_oxidize_tpu.obs.trace import NULL_SPAN
 from map_oxidize_tpu.ops.hashing import SENTINEL
 from map_oxidize_tpu.ops.segment_reduce import (
     _identity,
@@ -163,6 +163,11 @@ class StreamingEngineBase(abc.ABC):
         self._n_live_ub = 0      # host upper bound on live keys
         self._total_hint = None  # exact cap on distinct keys, if caller knows
 
+    def _span(self, name: str, **attrs):
+        """A span of the job's tracer; the null span with no ``Obs``."""
+        obs = self.obs
+        return obs.tracer.span(name, **attrs) if obs is not None else NULL_SPAN
+
     def _round_batch(self, n: int) -> int:
         """Padded size for an ``n``-row slice: next power of two, capped at
         ``feed_batch``.  Subclasses may round further (e.g. to a multiple of
@@ -181,6 +186,12 @@ class StreamingEngineBase(abc.ABC):
         p_lo[:n] = lo[start:stop]
         p_vals[:n] = vals[start:stop]
         return p_hi, p_lo, p_vals
+
+    def _put(self, padded):
+        """Ship one padded host batch toward the device; returns what
+        :meth:`_merge_batch` takes.  ``flush`` times this as
+        ``engine/put``."""
+        return padded
 
     def feed(self, out: MapOutput) -> None:
         """Stage one mapped chunk; flush to device when enough rows gather.
@@ -213,27 +224,26 @@ class StreamingEngineBase(abc.ABC):
             vals = np.concatenate([s[2] for s in self._stage])
         self._stage = []
         self._staged = 0
-        obs = self.obs
-        t0 = time.perf_counter() if obs is not None else 0.0
         try:
             # with-block, not manual enter/exit: a capacity/overflow abort
             # from the merge must still record the span (with its error
             # attribute) — the abort is exactly what a trace reader wants
-            with (obs.tracer.span("engine/flush", rows=int(hi.shape[0]))
-                  if obs is not None else contextlib.nullcontext()):
+            with self._span("engine/flush", rows=int(hi.shape[0])):
                 for start in range(0, hi.shape[0], self.feed_batch):
                     stop = min(start + self.feed_batch, hi.shape[0])
-                    self._merge_batch(self._pad(hi, lo, vals, start, stop))
+                    with self._span("engine/put"):
+                        batch = self._put(self._pad(hi, lo, vals, start,
+                                                    stop))
+                    with self._span("engine/dispatch"):
+                        self._merge_batch(batch)
                     self._merges += 1
                     if self._merges % self._check_every == 0:
                         self._health_sync()
         finally:
-            if obs is not None:
-                obs.registry.observe("engine/flush_ms",
-                                     (time.perf_counter() - t0) * 1e3)
-                obs.registry.count("engine/device_put_bytes",
-                                   hi.nbytes + lo.nbytes + vals.nbytes)
-                obs.registry.count("engine/flushes")
+            if self.obs is not None:
+                self.obs.registry.count("engine/device_put_bytes",
+                                        hi.nbytes + lo.nbytes + vals.nbytes)
+                self.obs.registry.count("engine/flushes")
 
     # --- capacity growth (shared; subclasses provide the two hooks) -------
 
@@ -266,14 +276,10 @@ class StreamingEngineBase(abc.ABC):
             # first (the only sync on the feed path, and only at a growth
             # edge the hint couldn't rule out).  The block is a pipeline
             # stall — the host sits in it while the prefetch thread piles
-            # up behind the feed — so it is timed into the obs bundle as
-            # feed-wait evidence at the engine layer.
-            t0 = time.perf_counter()
-            self._n_live_ub = self._read_live()
-            if self.obs is not None:
-                self.obs.registry.observe(
-                    "engine/growth_sync_ms",
-                    (time.perf_counter() - t0) * 1e3)
+            # up behind the feed — so it is its own span, feed-wait
+            # evidence at the engine layer.
+            with self._span("engine/growth_sync"):
+                self._n_live_ub = self._read_live()
             needed = self._n_live_ub + incoming
             if self._total_hint is not None:
                 needed = min(needed, self._total_hint)
@@ -297,17 +303,14 @@ class StreamingEngineBase(abc.ABC):
         self.capacity = new_cap
 
     def _health_sync(self) -> None:
-        """Periodic overflow check on the feed path, timed: the host
-        blocks here for the device (the one *mandatory* sync between
-        merges), which is exactly the stall the streaming pipeline's
-        ``feed_wait`` accounting wants attributed — a high
-        ``engine/health_sync_ms`` means the device, not the host map, is
-        the pipeline's limiting stage."""
-        t0 = time.perf_counter()
-        self._check_health()
-        if self.obs is not None:
-            self.obs.registry.observe("engine/health_sync_ms",
-                                      (time.perf_counter() - t0) * 1e3)
+        """Periodic overflow check on the feed path, as the
+        ``engine/health_sync`` span: the host blocks here for the device
+        (the one *mandatory* sync between merges), which is exactly the
+        stall the streaming pipeline's ``feed_wait`` accounting wants
+        attributed — a high ``engine/health_sync_ms`` means the device,
+        not the host map, is the pipeline's limiting stage."""
+        with self._span("engine/health_sync"):
+            self._check_health()
 
     @abc.abstractmethod
     def _read_live(self) -> int:
@@ -438,41 +441,45 @@ class DeviceReduceEngine(StreamingEngineBase):
         silently reinterpreted, so they take the plain three-plane path)."""
         return self.value_shape == () and self.value_dtype == np.dtype(np.int32)
 
-    def _merge_batch(self, padded) -> None:
+    def _queues(self, rows: int) -> bool:
+        """Whether a packed batch of ``rows`` takes the scan-batched path:
+        queued host-side, shipped B per launch (_round_batch pads every
+        packable slice to feed_batch under batching, so this is the only
+        packable case; a stale short slice would drain the queue first —
+        merge ORDER is the feed order at any B — and take the single
+        program)."""
+        return self.dispatch_batch > 1 and rows == self.feed_batch
+
+    def _put(self, padded):
         hi, lo, vals = padded
-        if self._packable():
-            packed = np.empty((3, hi.shape[0]), np.uint32)
-            packed[0] = hi
-            packed[1] = lo
-            packed[2] = vals.view(np.uint32)
-            incoming = self._incoming(hi.shape[0])
-            self._ensure_capacity(incoming)
-            if (self.dispatch_batch > 1
-                    and hi.shape[0] == self.feed_batch):
-                # scan-batched path: queue full-size packed batches and
-                # ship B per launch (_round_batch pads every packable
-                # slice to feed_batch under batching, so this is the
-                # only packable case; a stale short slice would drain
-                # the queue first — merge ORDER is the feed order at
-                # any B — and take the single program).
-                self._pack_queue.append(packed)
-                self._n_live_ub += incoming
-                if len(self._pack_queue) >= self.dispatch_batch:
-                    self._drain_packs()
-                return
+        if not self._packable():
+            return jax.device_put(padded, self.device)
+        packed = np.empty((3, hi.shape[0]), np.uint32)
+        packed[0] = hi
+        packed[1] = lo
+        packed[2] = vals.view(np.uint32)
+        if self._queues(hi.shape[0]):
+            return packed  # shipped stacked with the queue, by _drain_packs
+        return jax.device_put(packed, self.device)
+
+    def _merge_batch(self, batch) -> None:
+        if not self._packable():
             self._drain_packs()
-            *self._acc, self._n_unique, self._ovf = (
-                merge_packed_into_accumulator(
-                    *self._acc, self._ovf,
-                    jax.device_put(packed, self.device),
-                    combine=self.combine,
-                )
-            )
+            self.feed_device(*batch, count_rows=False)
+            return
+        incoming = self._incoming(batch.shape[1])
+        self._ensure_capacity(incoming)
+        if self._queues(batch.shape[1]):
+            self._pack_queue.append(batch)
             self._n_live_ub += incoming
+            if len(self._pack_queue) >= self.dispatch_batch:
+                self._drain_packs()
             return
         self._drain_packs()
-        batch = jax.device_put(padded, self.device)
-        self.feed_device(*batch, count_rows=False)
+        *self._acc, self._n_unique, self._ovf = (
+            merge_packed_into_accumulator(
+                *self._acc, self._ovf, batch, combine=self.combine))
+        self._n_live_ub += incoming
 
     def _drain_packs(self) -> None:
         """Ship the queued packed batches as ONE stacked transfer + ONE
@@ -495,12 +502,14 @@ class DeviceReduceEngine(StreamingEngineBase):
                 _identity(self.combine, np.int32)).view(np.uint32)
             self._pack_queue.extend(
                 [dead] * (b - len(self._pack_queue)))
-        stacked = np.stack(self._pack_queue)  # fresh: safe to hand off
+        with self._span("engine/put"):
+            stacked = jax.device_put(
+                np.stack(self._pack_queue),  # fresh: safe to hand off
+                self.device)
         self._pack_queue = []
         *self._acc, self._n_unique, self._ovf = (
             merge_packed_batch_into_accumulator(
-                *self._acc, self._ovf,
-                jax.device_put(stacked, self.device),
+                *self._acc, self._ovf, stacked,
                 combine=self.combine,
                 # per-chunk attribution counts the REAL merges, not the
                 # dead pad (consistent with the comms accounting)

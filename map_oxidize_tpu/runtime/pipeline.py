@@ -32,11 +32,13 @@ Exceptions (BaseException included: the kill-resume contract is a
 ``KeyboardInterrupt`` mid-map) propagate to the consumer after the items
 produced before them, exactly like serial iteration.
 
-``pipelined()`` is the driver-facing wrapper: depth <= 1 returns the
-iterator untouched (the serial baseline path, zero new machinery), and
-with an :class:`~map_oxidize_tpu.obs.Obs` bundle it records the counters
-(``pipeline/produce_ms``, ``pipeline/feed_wait_ms``) and the
-``pipeline/overlap_ratio`` gauge on exhaustion.
+Both halves are spans of the job's tracer, ``<name>/produce`` on the
+producer thread and ``<name>/feed_wait`` on the consumer's, and their
+sink keeps the ``pipeline/produce_ms`` / ``pipeline/feed_wait_ms``
+counters live per chunk.  ``pipelined()`` is the driver-facing wrapper:
+depth <= 1 returns the iterator untouched (the serial baseline path,
+zero new machinery), and with an :class:`~map_oxidize_tpu.obs.Obs`
+bundle it keeps the ``pipeline/overlap_ratio`` gauge.
 
 :class:`BlockStager` is the prefetcher grown into a **batching,
 double-buffered device stager** (the dispatch-floor attack's host half):
@@ -51,9 +53,9 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Iterable, Iterator, TypeVar
 
+from map_oxidize_tpu.obs.trace import Tracer
 from map_oxidize_tpu.utils.logging import get_logger
 
 _log = get_logger(__name__)
@@ -85,15 +87,13 @@ class ChunkPrefetcher:
         self._name = name
         self._stop = False
         self._err: BaseException | None = None
-        #: obs bundle for LIVE bucket feeds: with it, every consumed
-        #: item flushes the produce/wait deltas into the registry's
-        #: ``pipeline/produce_ms`` / ``pipeline/feed_wait_ms`` counters
-        #: (the attribution ledger and the heartbeat's where= token read
-        #: them mid-run — end-of-stream totals are identical to the old
-        #: exhaustion-time accounting, the cadence is what changed)
         self._obs = obs
-        self._reported_produce = 0.0
-        self._reported_wait = 0.0
+        #: the job's tracer: its spans time both halves and feed the
+        #: ``pipeline/*_ms`` counters live per chunk (the attribution
+        #: ledger and the heartbeat's where= token read them mid-run).
+        #: Without a job, a tracer that keeps nothing times them alone
+        self._tracer = (obs.tracer if obs is not None
+                        else Tracer(enabled=False, sink=_discard))
         # bind-on-spawn: the producer runs the job's host half (read +
         # tokenize/map), and anything it observes — a device-mapper
         # dispatch, a recompile warning — must route to the SPAWNING
@@ -113,11 +113,9 @@ class ChunkPrefetcher:
     # --- producer ---------------------------------------------------------
 
     def _produce(self) -> None:
-        tracer = (self._obs.tracer if self._obs is not None else None)
         seq = 0
         try:
             while not self._stop:
-                t0 = time.perf_counter()
                 # the producer half of the queue handoff: seq= pairs
                 # this span with the consumer's same-seq feed_wait span,
                 # the producer->consumer edge the critical-path DAG
@@ -125,18 +123,15 @@ class ChunkPrefetcher:
                 # this item.  Exhaustion uses the sentinel default so no
                 # StopIteration crosses the span (an error-tagged span
                 # in every healthy trace would read as a failure)
-                if tracer is not None and tracer.enabled:
-                    with tracer.span(f"{self._name}/produce",
-                                     seq=seq) as sp:
-                        item = next(self._it, _DONE)
-                        if item is _DONE:
-                            sp.set(exhausted=True)
-                else:
+                with self._tracer.span(f"{self._name}/produce",
+                                       seq=seq) as sp:
                     item = next(self._it, _DONE)
+                    if item is _DONE:
+                        sp.set(exhausted=True)
                 if item is _DONE:
                     return
                 seq += 1
-                self.produce_s += time.perf_counter() - t0
+                self.produce_s += sp.dur
                 # timed put loop instead of a blocking put: an abandoned
                 # consumer only drains once, so a producer stuck in a
                 # plain put() could miss the wakeup and leak its chunk
@@ -167,49 +162,26 @@ class ChunkPrefetcher:
             return 1.0
         return max(0.0, 1.0 - self.wait_s / self.produce_s)
 
-    def _flush_counters(self, chunks: int = 0) -> None:
-        """Report the produce/wait accumulated since the last flush into
-        the job registry (one locked add per counter per chunk — noise
-        at chunk cadence).  ``produce_s`` is written by the producer
-        thread; a torn read only shifts a delta to the next flush."""
-        if self._obs is None:
-            return
-        reg = self._obs.registry
-        dp = self.produce_s - self._reported_produce
-        dw = self.wait_s - self._reported_wait
-        if dp > 0:
-            self._reported_produce += dp
-            reg.count("pipeline/produce_ms", dp * 1e3)
-        if dw > 0:
-            self._reported_wait += dw
-            reg.count("pipeline/feed_wait_ms", dw * 1e3)
-        if chunks:
-            reg.count("pipeline/chunks", chunks)
-
     def __iter__(self) -> Iterator[T]:
         self._thread.start()
-        tracer = (self._obs.tracer if self._obs is not None else None)
         seq = 0
         try:
             while True:
-                t0 = time.perf_counter()
-                if tracer is not None and tracer.enabled:
-                    # the consumer half of the handoff: the span's wall
-                    # IS the stall waiting for item seq (zero when the
-                    # producer ran ahead) — same-seq as the producer's
-                    # produce span
-                    with tracer.span(f"{self._name}/feed_wait", seq=seq):
-                        item = self._q.get()
-                else:
+                # the consumer half of the handoff: the span's wall IS
+                # the stall waiting for item seq (zero when the producer
+                # ran ahead) — same-seq as the producer's produce span
+                with self._tracer.span(f"{self._name}/feed_wait",
+                                       seq=seq) as sp:
                     item = self._q.get()
                 seq += 1
-                self.wait_s += time.perf_counter() - t0
+                self.wait_s += sp.dur
                 if item is _DONE:
                     if self._err is not None:
                         raise self._err
                     return
                 self.items += 1
-                self._flush_counters(chunks=1)
+                if self._obs is not None:
+                    self._obs.registry.count("pipeline/chunks")
                 yield item
         finally:
             # abandon/exhaustion: release the producer if it is still
@@ -220,7 +192,10 @@ class ChunkPrefetcher:
                     self._q.get_nowait()
             except queue.Empty:
                 pass
-            self._flush_counters()
+
+
+def _discard(name: str, dur_s: float, self_s: float) -> None:
+    """Sink of a prefetcher with no job: its spans time, nothing keeps."""
 
 
 def chunk_groups(items: Iterable, batch: int) -> list:
@@ -291,10 +266,9 @@ def pipelined(it: Iterable[T], depth: int, obs=None,
     """
     if depth <= 1:
         return it
-    # the prefetcher itself feeds the pipeline/produce_ms and
+    # the prefetcher's spans feed the pipeline/produce_ms and
     # pipeline/feed_wait_ms counters LIVE per chunk (the attribution
-    # ledger's bucket feeds); totals at exhaustion are identical to the
-    # old end-of-stream accounting
+    # ledger's bucket feeds)
     pf = ChunkPrefetcher(it, depth - 1, name=name, obs=obs)
 
     def _set_ratio(reg) -> None:

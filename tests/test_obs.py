@@ -80,6 +80,101 @@ def test_disabled_tracer_is_noop_and_shared():
     assert [e for e in t.chrome_trace() if e["ph"] in ("X", "i")] == []
 
 
+def test_span_sink_self_time_excludes_same_thread_children_only():
+    """Fake clock: a driver span's self time is its duration less its
+    same-thread children; a producer-thread span that runs inside it
+    takes nothing off it."""
+    now = [0.0]
+    reg = MetricsRegistry()
+    t = Tracer(enabled=False, clock=lambda: now[0], sink=reg.record_span)
+    opened, release = threading.Event(), threading.Event()
+
+    def producer():
+        with t.span("map/produce"):
+            opened.set()
+            release.wait(5)
+
+    with t.span("phase/map+reduce"):          # 0 .. 10
+        now[0] = 1.0
+        th = threading.Thread(target=producer)
+        th.start()                            # produce 1 .. 8
+        opened.wait(5)
+        now[0] = 2.0
+        with t.span("engine/flush"):          # 2 .. 5
+            now[0] = 3.0
+            with t.span("engine/put"):        # 3 .. 4
+                now[0] = 4.0
+            now[0] = 5.0
+        now[0] = 8.0
+        release.set()
+        th.join()
+        now[0] = 10.0
+    c = reg.counters
+    assert c["span/phase/map+reduce_s"] == 10.0
+    assert c["span/phase/map+reduce_self_s"] == 7.0
+    assert c["span/engine/flush_s"] == 3.0
+    assert c["span/engine/flush_self_s"] == 2.0
+    assert c["span/engine/put_self_s"] == 1.0
+    assert c["span/map/produce_s"] == c["span/map/produce_self_s"] == 7.0
+    assert c["span/engine/flush_n"] == 1
+    # the keys older readers know, from the same spans
+    assert reg.phases["map+reduce"] == 10.0
+    assert c["pipeline/produce_ms"] == 7000.0
+    assert reg.histograms["engine/flush_ms"].total == 3000.0
+
+
+def test_span_sink_totals_without_trace_out():
+    """Tracing off: an Obs bundle's spans still land in its registry, and
+    no event list is kept."""
+    from map_oxidize_tpu.config import JobConfig
+
+    obs = Obs.from_config(JobConfig(input_path="unused"))
+    assert not obs.tracer.enabled
+    with obs.phase("map+reduce"):
+        with obs.feed_span(rows=3):
+            pass
+    obs.tracer.instant("marker")
+    s = obs.registry.summary()
+    assert s["span/engine/feed_block_n"] == 1
+    assert s["span/phase/map+reduce_s"] >= s["span/engine/feed_block_s"]
+    assert s["span/job/setup_n"] == 1     # closed by the first phase
+    assert "time/map+reduce_s" in s and s["feed_block_ms/count"] == 1
+    assert obs.tracer._events == []
+    assert [e for e in obs.tracer.chrome_trace() if e["ph"] != "M"] == []
+
+
+def test_feed_wait_counter_is_the_sum_of_feed_wait_spans():
+    from map_oxidize_tpu.config import JobConfig
+    from map_oxidize_tpu.runtime.pipeline import ChunkPrefetcher
+
+    obs = Obs.from_config(JobConfig(input_path="unused", trace_out="-"))
+    assert list(ChunkPrefetcher(iter(range(50)), 2, name="map",
+                                obs=obs)) == list(range(50))
+    waits = [e["dur"] for e in obs.tracer._events
+             if e["name"] == "map/feed_wait"]
+    assert len(waits) == 51               # one per item, one for the end
+    c = obs.registry.counters
+    assert c["pipeline/feed_wait_ms"] == pytest.approx(sum(waits) / 1e3,
+                                                       rel=1e-9)
+    assert c["pipeline/chunks"] == 50
+
+
+def test_feed_and_flush_histograms_keep_their_counts(tiny_corpus):
+    """One ``feed_block_ms`` observation per fed chunk and one
+    ``engine/flush_ms`` per device shipment, as the hand timers gave."""
+    from map_oxidize_tpu.config import JobConfig
+    from map_oxidize_tpu.runtime import run_job
+
+    r = run_job(JobConfig(input_path=str(tiny_corpus), output_path="",
+                          num_shards=1, metrics=False, chunk_bytes=256,
+                          batch_size=512), "wordcount")
+    m = r.metrics
+    assert m["chunks"] > 1
+    assert m["feed_block_ms/count"] == m["chunks"]
+    assert m["engine/flush_ms/count"] == m["engine/flushes"] >= 1
+    assert m["span/engine/flush_n"] == m["engine/flushes"]
+
+
 def test_chrome_trace_schema_and_json_round_trip():
     t = Tracer(enabled=True)
     with t.span("a", bytes=np.int64(7), dev=np.int32(0)):
