@@ -133,6 +133,9 @@ def _load_lib():
         lib.moxt_map_range.restype = ctypes.c_int64
         lib.moxt_map_range.argtypes = [ctypes.c_void_p, ctypes.c_void_p,
                                        ctypes.c_int64, ctypes.c_int64]
+        lib.moxt_range_cut.restype = ctypes.c_int64
+        lib.moxt_range_cut.argtypes = [ctypes.c_void_p, ctypes.c_void_p,
+                                       ctypes.c_int64, ctypes.c_int64]
         lib.moxt_map_docs_ex.restype = ctypes.c_int32
         lib.moxt_map_docs_ex.argtypes = [ctypes.c_void_p, ctypes.c_void_p,
                                          ctypes.c_int64, ctypes.c_int64,
@@ -381,6 +384,29 @@ class NativeStream:
         finally:
             self._lib.moxt_file_close(f)
 
+    def _range_ops(self, kind: str, chunk_bytes: int, p: int = 0):
+        """``(map_range(file, off) -> consumed, collect() -> output, what)``
+        of one scan kind over ``range_cut`` ranges on this stream:
+        ``words`` (combine map + dictionary delta), ``hashes`` (hash-only)
+        or ``hll`` (register fold).  The one-stream iterators below and
+        the workers of :class:`OrderedScan` share them."""
+        lib, st = self._lib, self._st
+        if kind == "words":
+            return (lambda f, off: lib.moxt_map_range(st, f, off,
+                                                      chunk_bytes),
+                    lambda: self._collect_locked(0, drain_dict=True),
+                    "map_range")
+        if kind == "hashes":
+            return (lambda f, off: lib.moxt_map_range_hashes(
+                        st, f, off, chunk_bytes),
+                    lambda: self._collect_hashes_locked(0),
+                    "map_range_hashes")
+        if kind == "hll":
+            return (lambda f, off: lib.moxt_map_range_hll(
+                        st, f, off, chunk_bytes, p),
+                    lambda: self._collect_hll_locked(0, p), "map_range_hll")
+        raise ValueError(f"unknown range scan kind {kind!r}")
+
     def iter_file(self, path: str, chunk_bytes: int, start_offset: int = 0):
         """Map a file via the C++ mmap path: zero kernel->user copies, chunk
         cuts chosen in C (last newline, then last whitespace, then hard cut —
@@ -390,10 +416,7 @@ class NativeStream:
         policy is deterministic in (offset, chunk_bytes), so the resumed
         chunk stream is identical to a fresh run's tail)."""
         return self._iter_file_ranges(
-            path, start_offset,
-            lambda f, off: self._lib.moxt_map_range(
-                self._st, f, off, chunk_bytes),
-            lambda: self._collect_locked(0, drain_dict=True), "map_range")
+            path, start_offset, *self._range_ops("words", chunk_bytes))
 
     def _collect_pairs_locked(self) -> MapOutput:
         n = int(self._lib.moxt_pairs_n(self._st))
@@ -465,10 +488,7 @@ class NativeStream:
         the same resume offsets) as :meth:`iter_file`.  Yields
         ``(MapOutput, next_offset)``."""
         return self._iter_file_ranges(
-            path, start_offset,
-            lambda f, off: self._lib.moxt_map_range_hashes(
-                self._st, f, off, chunk_bytes),
-            lambda: self._collect_hashes_locked(0), "map_range_hashes")
+            path, start_offset, *self._range_ops("hashes", chunk_bytes))
 
     def map_chunk_hll(self, chunk, p: int):
         """HLL-fold map of one chunk: the scan max-folds (top-p-bits bucket,
@@ -494,10 +514,7 @@ class NativeStream:
         offsets) as :meth:`iter_file_hashes`.  Yields
         ``(registers, n_tokens, next_offset)``."""
         for (regs, n_tokens), off in self._iter_file_ranges(
-                path, start_offset,
-                lambda f, off: self._lib.moxt_map_range_hll(
-                    self._st, f, off, chunk_bytes, p),
-                lambda: self._collect_hll_locked(0, p), "map_range_hll"):
+                path, start_offset, *self._range_ops("hll", chunk_bytes, p)):
             yield regs, n_tokens, off
 
     def resolve_file(self, path: str, chunk_bytes: int, hashes: np.ndarray,
@@ -734,8 +751,350 @@ def group_by_key_or_none(keys: np.ndarray, docs: np.ndarray,
     return out_off, out_docs[:n]
 
 
+def _gather(buf: np.ndarray, starts: np.ndarray,
+            lens: np.ndarray) -> np.ndarray:
+    """The bytes ``buf[starts[i]:starts[i] + lens[i]]`` of every i, end to
+    end, in one vectorised gather."""
+    total = int(lens.sum())
+    if total == 0:
+        return np.empty(0, np.uint8)
+    ends = np.cumsum(lens)
+    idx = np.arange(total, dtype=np.int64)
+    idx += np.repeat(starts - (ends - lens), lens)
+    return buf[idx]
+
+
+class DictUnion:
+    """The union of dictionary deltas drained by several streams, taken in
+    file order: each key is handed on once, by the first chunk that holds
+    it — exactly the rows one stream would have drained.
+
+    Each stream of a fan-out drains its own C++ dictionary, so k streams
+    drain most of a vocabulary k times; handing every repeat to the
+    driver's :class:`HashDictionary` would cost its per-row Python loop k
+    times over.  A repeat is dropped only if its bytes equal the kept
+    key's, compared byte for byte; the same hash with other bytes raises
+    the same collision error :class:`HashDictionary` does."""
+
+    def __init__(self):
+        self._h = np.empty(0, np.uint64)     # kept hashes, ascending
+        self._off = np.empty(0, np.int64)    # their bytes' offsets in _blob
+        self._len = np.empty(0, np.int64)
+        self._blob = np.empty(0, np.uint8)
+        #: repeated rows dropped so far
+        self.dropped = 0
+
+    def novel(self, delta: HashDictionary) -> HashDictionary:
+        """``delta`` less the keys an earlier chunk already handed on."""
+        hashes, lens, blob = delta.to_arrays()
+        n = len(hashes)
+        if n == 0:
+            return delta
+        lens = np.asarray(lens, np.int64)
+        starts = np.cumsum(lens) - lens
+        pos = np.searchsorted(self._h, hashes)
+        seen = pos < len(self._h)
+        seen[seen] = self._h[pos[seen]] == hashes[seen]
+        if seen.any():
+            rows = np.flatnonzero(seen)
+            kept = pos[rows]
+            bad = rows[self._len[kept] != lens[rows]]
+            if not bad.size:
+                mine = _gather(blob, starts[rows], lens[rows])
+                theirs = _gather(self._blob, self._off[kept], lens[rows])
+                diff = np.flatnonzero(mine != theirs)
+                if diff.size:
+                    ends = np.cumsum(lens[rows])
+                    bad = rows[[np.searchsorted(ends, diff[0], "right")]]
+            if bad.size:
+                r = int(bad[0])
+                k = int(pos[r])
+                prev = self._blob[self._off[k]:self._off[k] + self._len[k]]
+                mine = blob[starts[r]:starts[r] + lens[r]]
+                raise ValueError(
+                    f"64-bit hash collision: {prev.tobytes()!r} and "
+                    f"{mine.tobytes()!r} both hash to {int(hashes[r]):#x}")
+            self.dropped += rows.size
+            if rows.size == n:
+                return HashDictionary()
+            new = ~seen
+            hashes, lens = hashes[new], lens[new]
+            blob = _gather(blob, starts[new], lens)
+            starts = np.cumsum(lens) - lens
+        order = np.argsort(hashes, kind="stable")
+        at = np.searchsorted(self._h, hashes[order])
+        self._h = np.insert(self._h, at, hashes[order])
+        self._off = np.insert(self._off, at, starts[order] + len(self._blob))
+        self._len = np.insert(self._len, at, lens[order])
+        self._blob = np.concatenate([self._blob, blob])
+        if len(hashes) == n:
+            return delta
+        out = HashDictionary()
+        out.add_arrays(hashes, lens, blob.tobytes())
+        return out
+
+
+class _Failed:
+    """A range's error, held in its place in file order."""
+
+    __slots__ = ("exc",)
+
+    def __init__(self, exc: BaseException):
+        self.exc = exc
+
+
+class _Fanout:
+    """The shared state of one :class:`OrderedScan`: its worker threads
+    hold this, never the scan, so dropping the scan can stop them."""
+
+    def __init__(self, ngram, tokenizer, path, chunk_bytes, start_offset,
+                 workers, kind, p, emit, union):
+        self.ngram, self.tokenizer = ngram, tokenizer
+        self.path, self.chunk_bytes, self.kind, self.p = (
+            path, chunk_bytes, kind, p)
+        self.workers = workers
+        self.emit = emit
+        self.union = DictUnion() if union else None
+        self.cond = threading.Condition()
+        self.commit_lock = threading.Lock()
+        self.f = None
+        self.size = 0
+        self.window = 2
+        self.next_j, self.next_off = 0, start_offset  # the cut walk
+        self.commit_j = 0                 # next range to hand on in order
+        self.taken = 0                    # ranges the consumer took
+        self.results: dict = {}           # mapped, not yet in order
+        self.ready: dict = {}             # in order, not yet taken
+        self.halt = False
+        self.fatal: BaseException | None = None
+        self.produce_s = 0.0
+        self.threads: list[threading.Thread] = []
+        self.registry = None
+        self.tracer = None
+
+    def start(self) -> None:
+        from map_oxidize_tpu.obs.context import bind_current, current_obs
+        from map_oxidize_tpu.obs.trace import Tracer
+
+        lib = _load_lib()
+        f = lib.moxt_file_open(os.fsencode(self.path))
+        if not f:
+            raise OSError(f"cannot open/mmap {self.path!r}")
+        self.f = f
+        self.size = int(lib.moxt_file_size(f))
+        left = -(-max(self.size - self.next_off, 0) // self.chunk_bytes)
+        k = max(1, min(self.workers, left))
+        self.window = 2 * k
+        obs = current_obs()
+        self.tracer = (obs.tracer if obs is not None
+                       else Tracer(enabled=False, sink=lambda *a: None))
+        if obs is not None:
+            self.registry = obs.registry
+            self.registry.set("map/scan_workers", k)
+        # workers observe under the consumer's job (obs/context.py)
+        work = bind_current(self._work)
+        self.threads = [threading.Thread(target=work, daemon=True,
+                                         name=f"scan-{i}")
+                        for i in range(k)]
+        for t in self.threads:
+            t.start()
+
+    def _claim(self, stream):
+        """The next ``(j, off, len)`` of the cut walk, or None when the
+        scan is done or stopped; waits while the window is full."""
+        with self.cond:
+            while (not self.halt and self.next_off < self.size
+                   and self.next_j >= self.taken + self.window):
+                self.cond.wait()
+            if self.halt or self.next_off >= self.size:
+                return None
+            j, off = self.next_j, self.next_off
+            n = int(stream._lib.moxt_range_cut(stream._st, self.f, off,
+                                               self.chunk_bytes))
+            self.next_j += 1
+            # a zero cut would never advance: the serial loop's stall
+            self.next_off = off + n if n > 0 else self.size
+            return j, off, n
+
+    def _work(self) -> None:
+        try:
+            stream = NativeStream(self.ngram, self.tokenizer)
+        except BaseException as e:  # noqa: BLE001 — the consumer raises it
+            with self.cond:
+                self.fatal = self.fatal or e
+                self.cond.notify_all()
+            return
+        try:
+            map_range, collect, what = stream._range_ops(
+                self.kind, self.chunk_bytes, self.p)
+            while (claim := self._claim(stream)) is not None:
+                j, off, n = claim
+                dur = 0.0
+                try:
+                    if n <= 0:
+                        raise RuntimeError(f"native {what} stalled at {off}")
+                    with self.tracer.span("map/produce", seq=j) as sp, \
+                            stream._lock:
+                        consumed = int(map_range(self.f, off))
+                        if consumed < 0:
+                            _raise_map_error(-consumed)
+                        if consumed != n:
+                            raise RuntimeError(
+                                f"native {what} mapped {consumed} bytes at "
+                                f"{off}, its cut is {n}")
+                        item = self.emit(collect(), off + n)
+                    dur = sp.dur
+                except BaseException as e:  # noqa: BLE001 — raised in order
+                    item = _Failed(e)
+                self._file(j, item, dur)
+        finally:
+            stream.close()
+
+    def _file(self, j: int, item, dur: float) -> None:
+        """File range j's result, then hand on every range that is now
+        next in file order (whichever worker completes the prefix does
+        it; one at a time, so the dictionary union runs in file order)."""
+        with self.cond:
+            self.results[j] = item
+            self.produce_s += dur
+        with self.commit_lock:
+            while True:
+                with self.cond:
+                    cj = self.commit_j
+                    if self.halt or cj not in self.results:
+                        return
+                    item = self.results.pop(cj)
+                dropped = 0
+                if self.union is not None and not isinstance(item, _Failed):
+                    out = item[0]
+                    before = self.union.dropped
+                    try:
+                        out.dictionary = self.union.novel(out.dictionary)
+                    except BaseException as e:  # noqa: BLE001
+                        item = _Failed(e)
+                    dropped = self.union.dropped - before
+                if self.registry is not None and self.union is not None:
+                    self.registry.count("map/dict_rows_deduped", dropped)
+                with self.cond:
+                    self.ready[cj] = item
+                    self.commit_j = cj + 1
+                    if isinstance(item, _Failed):
+                        self.halt = True  # claim nothing past an error
+                    self.cond.notify_all()
+
+    def take(self):
+        """The next range's item in file order; StopIteration at the end."""
+        with self.cond:
+            j = self.taken
+            while j not in self.ready:
+                if self.fatal is not None:
+                    raise self.fatal
+                if self.next_off >= self.size and j >= self.next_j:
+                    raise StopIteration
+                self.cond.wait()
+            item = self.ready.pop(j)
+            self.taken = j + 1
+            self.cond.notify_all()  # a window slot is free
+        if isinstance(item, _Failed):
+            raise item.exc
+        return item
+
+    def close(self) -> None:
+        with self.cond:
+            self.halt = True
+            self.cond.notify_all()
+        me = threading.current_thread()
+        for t in self.threads:
+            if t is not me:
+                t.join()
+        self.threads = []
+        # unmap only once no worker can be inside a range
+        if self.f:
+            _load_lib().moxt_file_close(self.f)
+            self.f = None
+
+
+class OrderedScan:
+    """A native mmap range scan fanned out over several streams and handed
+    back in file order.
+
+    The cut walk is the one-stream iterators' own (``moxt_range_cut``:
+    deterministic in ``(offset, chunk_bytes)``), taken one cut at a time
+    under a lock: each of k worker threads claims the next cut, maps it on
+    a native stream of its own (C runs with the GIL released), and files
+    the result.  Results are handed on strictly in file order, so the
+    consumer sees the one-stream scan's ``(output, next_offset)`` sequence
+    with the same rows, records and offsets chunk by chunk; the
+    dictionary deltas pass through a :class:`DictUnion`, so each key
+    reaches the consumer once, as one stream would drain it.  At most
+    ``2k`` ranges are claimed and not yet taken: that bounds host memory.
+
+    k is ``workers`` capped by the ranges left; with k = 1 this is the
+    one-stream schedule on a producer thread.  The job's registry gets the
+    gauge ``map/scan_workers`` (k) and the counter
+    ``map/dict_rows_deduped``; each range is a ``map/produce`` span on the
+    job's tracer, on its worker's thread.
+
+    Errors keep the serial contract: a map error in range j (invalid
+    UTF-8, a collision, a stalled cut) is raised after every range before
+    j was handed on, and stops the scan.  :meth:`close` — also run at the
+    end, on an error and when the scan is dropped — stops the workers,
+    joins them and unmaps the file.  ``runs_ahead`` tells a prefetcher
+    (:mod:`map_oxidize_tpu.runtime.pipeline`) to pull the scan on its
+    consumer's thread instead of wrapping it in a thread of its own."""
+
+    runs_ahead = True
+
+    def __init__(self, ngram: int, tokenizer: str, path: str,
+                 chunk_bytes: int, start_offset: int, workers: int,
+                 kind: str, p: int = 0, emit=lambda out, off: (out, off),
+                 union: bool = False):
+        self._state = _Fanout(ngram, tokenizer, path, chunk_bytes,
+                              start_offset, workers, kind, p, emit, union)
+        self._started = False
+
+    @property
+    def produce_s(self) -> float:
+        """Summed seconds the workers spent mapping ranges."""
+        return self._state.produce_s
+
+    def then(self, fn) -> "OrderedScan":
+        """Apply ``fn`` to each item on its worker thread (before the scan
+        starts)."""
+        emit = self._state.emit
+        self._state.emit = lambda out, off: fn(emit(out, off))
+        return self
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        if not self._started:
+            self._started = True
+            try:
+                self._state.start()
+            except BaseException:
+                self.close()
+                raise
+        try:
+            return self._state.take()
+        except BaseException:
+            self.close()
+            raise
+
+    def close(self) -> None:
+        self._state.close()
+
+    def __del__(self):
+        try:
+            self.close()
+        except Exception:
+            pass
+
+
 class StreamPool:
-    """One :class:`NativeStream` per calling thread.
+    """One :class:`NativeStream` per calling thread; file scans fan out
+    over streams of their own (:class:`OrderedScan`).
 
     A single stream serializes on its lock, which would collapse a
     multi-worker map phase onto one core; per-thread streams keep the
@@ -763,8 +1122,12 @@ class StreamPool:
     def map_chunk(self, chunk) -> MapOutput:
         return self.get().map_chunk(chunk)
 
-    def iter_file(self, path: str, chunk_bytes: int, start_offset: int = 0):
-        return self.get().iter_file(path, chunk_bytes, start_offset)
+    def iter_file(self, path: str, chunk_bytes: int, start_offset: int = 0,
+                  workers: int = 1) -> OrderedScan:
+        """:meth:`NativeStream.iter_file` over ``workers`` streams of the
+        scan's own (:class:`OrderedScan`): the same items, in order."""
+        return OrderedScan(self.ngram, self.tokenizer, path, chunk_bytes,
+                           start_offset, workers, "words", union=True)
 
     def map_docs(self, chunk, base_doc: int = 0) -> MapOutput:
         return self.get().map_docs(chunk, base_doc)
@@ -774,8 +1137,11 @@ class StreamPool:
         return self.get().iter_file_docs(path, chunk_bytes, start_offset)
 
     def iter_file_hashes(self, path: str, chunk_bytes: int,
-                         start_offset: int = 0):
-        return self.get().iter_file_hashes(path, chunk_bytes, start_offset)
+                         start_offset: int = 0,
+                         workers: int = 1) -> OrderedScan:
+        """:meth:`NativeStream.iter_file_hashes` over ``workers`` streams."""
+        return OrderedScan(self.ngram, self.tokenizer, path, chunk_bytes,
+                           start_offset, workers, "hashes")
 
     def map_chunk_hashes(self, chunk) -> MapOutput:
         return self.get().map_chunk_hashes(chunk)
@@ -784,8 +1150,12 @@ class StreamPool:
         return self.get().map_chunk_hll(chunk, p)
 
     def iter_file_hll(self, path: str, chunk_bytes: int, p: int,
-                      start_offset: int = 0):
-        return self.get().iter_file_hll(path, chunk_bytes, p, start_offset)
+                      start_offset: int = 0,
+                      workers: int = 1) -> OrderedScan:
+        """:meth:`NativeStream.iter_file_hll` over ``workers`` streams."""
+        return OrderedScan(self.ngram, self.tokenizer, path, chunk_bytes,
+                           start_offset, workers, "hll", p,
+                           emit=lambda out, off: (*out, off))
 
     def resolve_file(self, path: str, chunk_bytes: int, hashes,
                      early_stop: bool = True):

@@ -238,7 +238,7 @@ struct Slot {
   uint32_t count;
   uint32_t len;
   uint32_t epoch;
-  uint32_t pad_;
+  uint32_t seq;      // chunk table: rank of first occurrence in the chunk
 };
 
 struct Table {
@@ -491,7 +491,7 @@ inline int chunk_upsert(MoxtState* st, const uint8_t* p, uint32_t len,
       s.len = len;
       s.aref = st->chunk_arena.append(p, len);
       s.epoch = t.epoch;
-      t.n++;
+      s.seq = (uint32_t)t.n++;
       return UP_OK;
     }
     if (s.hash == h) {
@@ -1150,16 +1150,17 @@ void moxt_pairs_read(MoxtState* st, uint64_t* hashes, int64_t* docs) {
 }
 
 // Copy the chunk's compacted (hash, count) columns into caller buffers of
-// size moxt_chunk_unique().
+// size moxt_chunk_unique(), in order of first occurrence in the chunk: the
+// rows are a function of the chunk alone, not of the table capacity this
+// stream's earlier chunks grew it to, so any stream maps a range to the
+// same bytes.
 void moxt_chunk_read(MoxtState* st, uint64_t* hashes, int32_t* counts) {
   const Table& t = st->chunk;
-  int64_t out = 0;
   for (int64_t i = 0; i < t.cap; i++) {
     const Slot& s = t.slots[i];
     if (s.epoch != t.epoch || s.count == 0) continue;
-    hashes[out] = s.hash;
-    counts[out] = (int32_t)s.count;
-    out++;
+    hashes[s.seq] = s.hash;
+    counts[s.seq] = (int32_t)s.count;
   }
 }
 
@@ -1259,6 +1260,15 @@ static int64_t range_cut(MoxtState* st, MoxtFile* f, int64_t off,
     }
   }
   return len;
+}
+
+// The length of the range at `off` that every range mapper below would
+// map: the cut alone, so a scan can walk its cuts ahead of mapping them.
+// 0 at EOF.
+int64_t moxt_range_cut(MoxtState* st, MoxtFile* f, int64_t off,
+                       int64_t want) {
+  if (!st || !f || off < 0 || off >= f->size || want <= 0) return 0;
+  return range_cut(st, f, off, want);
 }
 
 // Map one chunk straight from the mapping: [off, off + consumed).  Returns

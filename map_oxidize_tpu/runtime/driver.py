@@ -10,6 +10,7 @@ config, metrics, retries, checkpointing hooks, and deterministic output.
 
 from __future__ import annotations
 
+import os
 import time
 from collections.abc import Mapping
 from dataclasses import dataclass, field
@@ -75,6 +76,17 @@ def collect_engine_kw(config: JobConfig) -> dict:
     sentinel means 'engine default', so the key is only passed when set."""
     return ({"max_rows": config.collect_max_rows}
             if config.collect_max_rows else {})
+
+
+def scan_workers(config: JobConfig) -> int:
+    """Host cores the native mmap scan may fan out over: the configured
+    map workers, capped by the CPUs this process may run on (the scan
+    caps it again by the ranges left)."""
+    try:
+        cpus = len(os.sched_getaffinity(0))
+    except AttributeError:  # no affinity call on this platform
+        cpus = os.cpu_count() or 1
+    return max(1, min(config.num_map_workers, cpus))
 
 
 def solved_transport(config: JobConfig, obs: Obs) -> str:
@@ -448,15 +460,16 @@ def _run_wordcount_body(config: JobConfig, obs: Obs, mapper: Mapper,
             _, chunk_bytes = plan_chunks(config.input_path, config.chunk_bytes)
             # native mmap fast path: C++ scans page-cache pages in place
             # (zero kernel->user copies) and owns the chunk cuts
+            workers = scan_workers(config)
             if hasattr(mapper, "map_file"):
-                native_file_iter = mapper.map_file(config.input_path,
-                                                   chunk_bytes, resume_off)
+                native_file_iter = mapper.map_file(
+                    config.input_path, chunk_bytes, resume_off, workers)
             if native_file_iter is not None:
                 _log.debug(
-                    "native mmap map path: chunks map inline in C++; "
-                    "num_map_workers/max_retries do not apply (a map error "
-                    "here is a hash collision or invalid UTF-8, which no "
-                    "retry can fix)")
+                    "native mmap map path: chunks map in C++ on up to %d "
+                    "host cores, handed on in file order; max_retries does "
+                    "not apply (a map error here is a hash collision or "
+                    "invalid UTF-8, which no retry can fix)", workers)
             else:
                 chunks = _track_offsets(
                     iter_chunks(config.input_path, chunk_bytes, resume_off),
@@ -1227,7 +1240,7 @@ def _run_distinct_body(config: JobConfig, obs: Obs) -> DistinctResult:
     with obs.phase("split"):
         _, chunk_bytes = plan_chunks(config.input_path, config.chunk_bytes)
         file_iter = mapper.map_file(config.input_path, chunk_bytes,
-                                    resume_off)
+                                    resume_off, scan_workers(config))
         if file_iter is None:
             offsets: dict[int, int] = {}
             chunks = _track_offsets(

@@ -74,6 +74,12 @@ class ChunkPrefetcher:
     abandon path sets a stop flag and drains the queue so a producer
     blocked on ``put`` wakes and exits instead of pinning ``depth``
     chunks of host memory until process end.
+
+    A source that runs its own producers ahead of its consumer
+    (``runs_ahead``, the native scan's :class:`~map_oxidize_tpu.native.
+    build.OrderedScan`) gets no thread: the consumer pulls it directly
+    under ``<name>/feed_wait``, its producers time their own work,
+    ``produce_s`` is theirs, and abandoning the iteration closes it.
     """
 
     def __init__(self, it: Iterable[T], depth: int, name: str = "pipeline",
@@ -83,6 +89,7 @@ class ChunkPrefetcher:
         from map_oxidize_tpu.obs.context import bind_current
 
         self._it = iter(it)
+        self._inline = getattr(it, "runs_ahead", False)
         self._q: queue.Queue = queue.Queue(maxsize=depth)
         self._name = name
         self._stop = False
@@ -163,7 +170,14 @@ class ChunkPrefetcher:
         return max(0.0, 1.0 - self.wait_s / self.produce_s)
 
     def __iter__(self) -> Iterator[T]:
-        self._thread.start()
+        if self._inline:
+            def get():
+                item = next(self._it, _DONE)
+                self.produce_s = self._it.produce_s
+                return item
+        else:
+            self._thread.start()
+            get = self._q.get
         seq = 0
         try:
             while True:
@@ -172,7 +186,7 @@ class ChunkPrefetcher:
                 # ran ahead) — same-seq as the producer's produce span
                 with self._tracer.span(f"{self._name}/feed_wait",
                                        seq=seq) as sp:
-                    item = self._q.get()
+                    item = get()
                 seq += 1
                 self.wait_s += sp.dur
                 if item is _DONE:
@@ -187,6 +201,8 @@ class ChunkPrefetcher:
             # abandon/exhaustion: release the producer if it is still
             # blocked, then let the daemon thread unwind
             self._stop = True
+            if self._inline:
+                self._it.close()
             try:
                 while True:
                     self._q.get_nowait()

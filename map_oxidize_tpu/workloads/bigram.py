@@ -110,14 +110,16 @@ class BigramMapper(Mapper):
                           early_stop: bool = True) -> RescanDictionary:
         return RescanDictionary(self._native, path, chunk_bytes, early_stop)
 
-    def map_file(self, path: str, chunk_bytes: int, start_offset: int = 0):
+    def map_file(self, path: str, chunk_bytes: int, start_offset: int = 0,
+                 workers: int = 1):
         """Native mmap fast path (see WordCountMapper.map_file)."""
         if self._native is None:
             return None
         if self.hash_only:
             return self._native.iter_file_hashes(path, chunk_bytes,
-                                                 start_offset)
-        return self._native.iter_file(path, chunk_bytes, start_offset)
+                                                 start_offset, workers)
+        return self._native.iter_file(path, chunk_bytes, start_offset,
+                                      workers)
 
     def map_chunk(self, chunk: bytes) -> MapOutput:
         if self._native is not None:

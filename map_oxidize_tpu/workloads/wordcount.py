@@ -57,13 +57,17 @@ class WordCountMapper(Mapper):
             self._native = bindings.stream_or_none(ngram=1,
                                                    tokenizer=tokenizer)
 
-    def map_file(self, path: str, chunk_bytes: int, start_offset: int = 0):
-        """Native mmap fast path: a ``(MapOutput, next_offset)`` generator
-        over the file, or None when the C++ loop is unavailable (driver falls
-        back to the streaming splitter + map_chunk)."""
+    def map_file(self, path: str, chunk_bytes: int, start_offset: int = 0,
+                 workers: int = 1):
+        """Native mmap fast path: ``(MapOutput, next_offset)`` items over the
+        file in order, mapped on up to ``workers`` host cores
+        (:class:`~map_oxidize_tpu.native.build.OrderedScan`), or None when
+        the C++ loop is unavailable (driver falls back to the streaming
+        splitter + map_chunk)."""
         if self._native is None:
             return None
-        return self._native.iter_file(path, chunk_bytes, start_offset)
+        return self._native.iter_file(path, chunk_bytes, start_offset,
+                                      workers)
 
     def map_chunk(self, chunk: bytes) -> MapOutput:
         if self._native is not None:
