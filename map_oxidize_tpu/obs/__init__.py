@@ -95,7 +95,7 @@ from map_oxidize_tpu.obs.metrics import (
     sample_device_memory,
     sample_host_memory,
 )
-from map_oxidize_tpu.obs.trace import NULL_SPAN, Span, Tracer
+from map_oxidize_tpu.obs.trace import NULL_SPAN, Span, Tracer, span_of
 
 __all__ = [
     "Heartbeat",
@@ -108,6 +108,7 @@ __all__ = [
     "Tracer",
     "sample_device_memory",
     "sample_host_memory",
+    "span_of",
 ]
 
 

@@ -37,7 +37,7 @@ from __future__ import annotations
 import threading
 import time
 
-from map_oxidize_tpu.obs.trace import NULL_SPAN
+from map_oxidize_tpu.obs.trace import span_of
 from map_oxidize_tpu.utils.logging import get_logger
 
 _log = get_logger(__name__)
@@ -533,8 +533,7 @@ class ObservedJit:
             # (device_wait_s reads it), and the ledger's compute sample
             t1 = time.perf_counter()
             try:
-                with (_obs.tracer.span("xprof/ready_wait", program=self._name)
-                      if _obs is not None else NULL_SPAN):
+                with span_of(_obs, "xprof/ready_wait", program=self._name):
                     jax.block_until_ready(out)
                 ready_ms = (time.perf_counter() - t1) * 1e3
             except Exception:

@@ -142,6 +142,13 @@ SPAN_HISTOGRAMS = {
     "engine/growth_sync": "engine/growth_sync_ms",
 }
 
+#: spans whose durations also add up, in seconds, under the key its
+#: readers (bench.py, chip_smoke.py, the benchmark) know
+SPAN_SECONDS = {
+    "kmeans/put": "time/transfer_s",
+    "kmeans/fit": "time/iter_s",
+}
+
 
 def _span_alias(name: str) -> tuple[str | None, str | None]:
     """``(kind, key)`` of the older key a span also writes, if any."""
@@ -154,6 +161,8 @@ def _span_alias(name: str) -> tuple[str | None, str | None]:
         return "counter_ms", "pipeline/produce_ms"
     if name in SPAN_HISTOGRAMS:
         return "hist_ms", SPAN_HISTOGRAMS[name]
+    if name in SPAN_SECONDS:
+        return "counter_s", SPAN_SECONDS[name]
     return None, None
 
 
@@ -235,8 +244,9 @@ class MetricsRegistry:
         ``span/<name>_n``, and writes the keys older readers know for the
         same region: ``phase/<p>`` the ``time/<p>_s`` phase clock,
         ``<prefetcher>/produce`` and ``/feed_wait`` the ``pipeline/*_ms``
-        counters, and the spans of :data:`SPAN_HISTOGRAMS` their
-        histograms."""
+        counters, the spans of :data:`SPAN_HISTOGRAMS` their
+        histograms and those of :data:`SPAN_SECONDS` their ``time/*_s``
+        keys."""
         keys = self._span_keys.get(name)
         if keys is None:
             keys = self._span_keys[name] = (
@@ -252,6 +262,8 @@ class MetricsRegistry:
                 self.phases[alias] = self.phases.get(alias, 0.0) + dur_s
             elif kind == "counter_ms":
                 c[alias] = c.get(alias, 0.0) + dur_s * 1e3
+            elif kind == "counter_s":
+                c[alias] = c.get(alias, 0.0) + dur_s
             elif kind == "hist_ms":
                 h = self.histograms.get(alias)
                 if h is None:

@@ -70,6 +70,12 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+def span_of(obs, name: str, **attrs):
+    """A span of ``obs``'s tracer (an :class:`~map_oxidize_tpu.obs.Obs`);
+    the null span where there is no ``Obs``."""
+    return obs.tracer.span(name, **attrs) if obs is not None else NULL_SPAN
+
+
 def _annotation_type():
     """``jax.profiler.TraceAnnotation`` once the process has imported
     jax, else None: no profiler session can exist before, and a span must

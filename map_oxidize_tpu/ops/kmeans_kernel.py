@@ -14,7 +14,9 @@ re-measured on current code).  The driver therefore keeps the XLA path
 (``assign_and_sum``); this kernel stays as the tested template for
 shapes XLA might handle worse.
 
-The numerics mirror ``assign_and_sum`` exactly per mode:
+The numerics follow ``assign_and_sum`` per mode, but for the ``highest``
+partial sums, which ``assign_and_sum`` takes as three exact-product
+passes over ``exact_pieces`` of the points:
 
 * ``highest`` — f32 operands, ``Precision.HIGHEST`` matmuls;
 * ``bf16`` — operands cast to bfloat16, f32 accumulation
@@ -118,9 +120,10 @@ def _build(n: int, n_pad: int, d: int, k: int, precision: str,
 
 def fused_assign_sum(p, c, k: int, precision: str = "highest", w=None,
                      interpret: bool = False):
-    """Drop-in for :func:`workloads.kmeans.assign_and_sum` on TPU:
-    returns ``(sums (k, d), counts (k,))`` with the same per-mode
-    numerics, one pass over the points, no (n, k) HBM intermediates.
+    """The partial sums of :func:`workloads.kmeans.assign_and_sum` on
+    TPU: returns ``(sums (k, d), counts (k,))`` (no assignment) with the
+    same per-mode numerics, one pass over the points, no (n, k) HBM
+    intermediates.
     Traceable under jit/fori_loop/shard_map (grid count is static in the
     padded row count).  ``w=None`` masks the internal tail padding in
     place; pass explicit weights only when rows genuinely carry them."""

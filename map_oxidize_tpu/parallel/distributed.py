@@ -1919,7 +1919,7 @@ def _run_distributed_kmeans(config: JobConfig, obs: Obs
             step_fn = make_fit_fn(mesh, k, d, 1, config.kmeans_precision)
             c = jax.device_put(centroids, NamedSharding(mesh, P()))
             for i in range(remaining):
-                c = step_fn(p_dev, w_dev, c)
+                c = step_fn(p_dev, w_dev, c)[0]
                 done = start_iter + i + 1
                 c_np = np.asarray(rep(c))
                 if proc == 0:
@@ -1936,7 +1936,7 @@ def _run_distributed_kmeans(config: JobConfig, obs: Obs
                                  config.kmeans_precision)
             out = np.asarray(rep(fit_fn(
                 p_dev, w_dev,
-                jax.device_put(centroids, NamedSharding(mesh, P())))))
+                jax.device_put(centroids, NamedSharding(mesh, P())))[0]))
     if config.output_path and proc == 0:
         from map_oxidize_tpu.workloads.kmeans import write_centroids
 

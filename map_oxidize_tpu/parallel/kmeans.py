@@ -39,31 +39,39 @@ def make_fit_fn(mesh, k: int, d: int, loop_iters: int,
     matmul) + one-hot partial sums (both from
     :func:`workloads.kmeans.assign_and_sum` — the single-device step's
     exact numerics, including the ``--kmeans-precision`` bf16 mode),
-    joined by ONE ``(k, d+1)`` psum per iteration.  Shared verbatim by
-    the single-controller sharded fit and the multi-process runner (same
-    XLA program, different array assembly), so the paths cannot drift."""
-    from map_oxidize_tpu.workloads.kmeans import assign_and_sum
+    joined by ONE ``(k, d+1)`` psum per iteration.  Returns ``(c_T,
+    c_{T-1}, labels)``: the replicated final centroids, the replicated
+    centroids the last step assigned against, and that step's row-sharded
+    ``(n_pad,)`` int32 assignment.  Shared verbatim by the
+    single-controller sharded fit and the multi-process runner (same XLA
+    program, different array assembly), so the paths cannot drift."""
+    from map_oxidize_tpu.workloads.kmeans import assign_and_sum, exact_pieces
 
     def fit(p, w, c):
         """Per-shard body: p, w are this shard's block; c is replicated."""
+        pieces = exact_pieces(p) if precision == "highest" else None
 
-        def step(_, c):
-            sums, counts = assign_and_sum(p, c, k, precision, w)
+        def step(c):
+            sums, counts, cid = assign_and_sum(p, c, k, precision, w, pieces)
             # ONE collective per iteration: the (k, d+1) partials
             joined = lax.psum(
                 jnp.concatenate([sums, counts[:, None]], axis=1), SHARD_AXIS)
             sums, counts = joined[:, :d], joined[:, d]
             return jnp.where(counts[:, None] > 0,
-                             sums / jnp.maximum(counts[:, None], 1.0), c)
+                             sums / jnp.maximum(counts[:, None], 1.0), c), cid
 
-        return lax.fori_loop(0, loop_iters, step, c)
+        # loop_iters - 1 steps that drop their assignment, then the last,
+        # which keeps this shard's rows' labels
+        prev = lax.fori_loop(0, loop_iters - 1, lambda _, cc: step(cc)[0], c)
+        new, cid = step(prev)
+        return new, prev, cid
 
     from map_oxidize_tpu.obs.compile import observed_jit
 
     return observed_jit("kmeans/fit_sharded", jax.jit(shard_map(
         fit, mesh=mesh,
         in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P()),
-        out_specs=P(),
+        out_specs=(P(), P(), P(SHARD_AXIS)),
     )), tag=(k, loop_iters, precision))
 
 
@@ -130,7 +138,7 @@ def _build_stream_step(mesh, k: int, precision: str, batch: int,
         # c (k, d) and acc (k, d+1) replicated
         def chunk_step(a, xs):
             chunk, w = xs
-            sums, counts = assign_and_sum(chunk, c, k, precision, w)
+            sums, counts, _ = assign_and_sum(chunk, c, k, precision, w)
             part = lax.psum(
                 jnp.concatenate([sums, counts[:, None]], axis=1),
                 SHARD_AXIS)
@@ -431,56 +439,53 @@ def kmeans_fit_streamed(path: str, centroids, iters: int = 1,
 
 def kmeans_fit_sharded(points, centroids, iters: int = 1, mesh=None,
                        num_shards: int = 0, backend: str = "auto",
-                       on_iter=None, timings: dict | None = None,
-                       precision: str = "highest", obs=None):
+                       on_iter=None, precision: str = "highest", obs=None):
     """Run ``iters`` k-means iterations with points sharded over the mesh.
 
     ``points``: host ``(n, d)`` float32 (rows pad to a multiple of the shard
     count with zero-weight rows, so padding never moves a centroid).
-    Returns the final centroids as NumPy ``(k, d)``.
+    Returns a :class:`~map_oxidize_tpu.workloads.kmeans.KMeansFit`: the
+    final centroids, the centroids the last step assigned against, and
+    that step's ``(n,)`` assignment, left on the devices.
 
     ``on_iter(i, centroids_np)`` (checkpoint hook): when given, the compiled
     body runs one iteration per call — points stay sharded in HBM; only the
     replicated ``(k, d)`` centroids and one psum per iteration move.
 
-    ``timings`` mirrors :func:`workloads.kmeans.kmeans_fit_device`:
-    ``transfer_s`` (the one sharded put) and, on the uninterleaved path
-    only, ``iter_s`` (fetch-forced iteration chain — the MFU region).
+    ``obs`` times the same spans as
+    :func:`workloads.kmeans.kmeans_fit_device`: ``kmeans/load`` (the
+    padded host array and its weights), ``kmeans/put`` (the one sharded
+    put) and, on the uninterleaved path only, ``kmeans/fit``.
     """
-    import time
+    from map_oxidize_tpu.obs.trace import span_of
+    from map_oxidize_tpu.workloads.kmeans import KMeansFit, host_points
+
+    if iters < 1:
+        raise ValueError(f"k-means fit needs iters >= 1, got {iters}")
     if mesh is None:
         mesh = make_mesh(num_shards, backend)
     S = mesh.shape[SHARD_AXIS]
-    points = np.asarray(points, np.float32)
     centroids = np.asarray(centroids, np.float32)
-    n, d = points.shape
     k = centroids.shape[0]
-
-    n_pad = -(-n // S) * S
-    if n_pad != n:
-        points = np.concatenate(
-            [points, np.zeros((n_pad - n, d), np.float32)])
-    if precision == "bf16":
-        # bf16 HBM storage: same rationale as kmeans_fit_device — the
-        # per-iteration full read is the bottleneck, and the matmul
-        # operand is cast down regardless
-        import ml_dtypes
-
-        points = points.astype(ml_dtypes.bfloat16)
-    weights = np.zeros(n_pad, np.float32)
-    weights[:n] = 1.0
+    with span_of(obs, "kmeans/load"):
+        points = host_points(points, precision)
+        n, d = points.shape
+        n_pad = -(-n // S) * S
+        if n_pad != n:
+            points = np.concatenate(
+                [points, np.zeros((n_pad - n, d), points.dtype)])
+        weights = np.zeros(n_pad, np.float32)
+        weights[:n] = 1.0
 
     fit_fn = make_fit_fn(mesh, k, d,
                          1 if on_iter is not None else iters, precision)
     row = NamedSharding(mesh, P(SHARD_AXIS))
     rep = NamedSharding(mesh, P())
-    t0 = time.perf_counter()
-    p_dev = jax.device_put(points, row)
-    w_dev = jax.device_put(weights, row)
-    p_dev.block_until_ready()
-    w_dev.block_until_ready()
-    if timings is not None:
-        timings["transfer_s"] = time.perf_counter() - t0
+    with span_of(obs, "kmeans/put"):
+        p_dev = jax.device_put(points, row)
+        w_dev = jax.device_put(weights, row)
+        p_dev.block_until_ready()
+        w_dev.block_until_ready()
     c_dev = jax.device_put(centroids, rep)
     if obs is not None and S > 1:
         # one (k, d+1) partials psum per iteration — the fit's only
@@ -488,14 +493,16 @@ def kmeans_fit_sharded(points, centroids, iters: int = 1, mesh=None,
         for _ in range(iters):
             obs.registry.comm("psum", "kmeans/fit_sharded",
                               S * k * (d + 1) * 4, shape=(k, d + 1))
-    t0 = time.perf_counter()
     if on_iter is None:
-        out = np.asarray(fit_fn(p_dev, w_dev, c_dev))  # asarray forces
-        if timings is not None:
-            timings["iter_s"] = time.perf_counter() - t0
-        return out
+        with span_of(obs, "kmeans/fit"):
+            # the fetch forces the chain and brings back the two (k, d)
+            new, prev, labels = fit_fn(p_dev, w_dev, c_dev)
+            new, prev = jax.device_get((new, prev))
+        return KMeansFit(new, prev, labels[:n] if n_pad != n else labels)
     c = c_dev
     for i in range(iters):
-        c = fit_fn(p_dev, w_dev, c)
+        prev = c
+        c, _, labels = fit_fn(p_dev, w_dev, c)
         on_iter(i + 1, np.asarray(c))
-    return np.asarray(c)
+    return KMeansFit(np.asarray(c), np.asarray(prev),
+                     labels[:n] if n_pad != n else labels)

@@ -774,11 +774,22 @@ def _finish_inverted_index(config, obs, postings, ckpt, records_in,
 @dataclass
 class KMeansResult:
     """Final centroids plus per-phase metrics (the k-means analogue of
-    JobResult; there is no top-k or word dictionary to report)."""
+    JobResult; there is no top-k or word dictionary to report).
+
+    The HBM-resident fits also return each point's cluster: ``labels``,
+    the ``(n,)`` int32 assignment of the last step, and ``assigned_from``,
+    the ``(k, d)`` centroids it was made against (so ``centroids`` are the
+    means of the points over ``labels``, an empty cluster keeping its
+    ``assigned_from`` row).  ``labels`` stays a device array until the
+    caller reads it (``np.asarray``), so a job that never reads it pays
+    no readback.  The streamed paths, and a resume whose snapshot already
+    covers every iteration, return ``None`` for both."""
 
     centroids: np.ndarray
     metrics: dict = field(default_factory=dict)
     trace: list | None = None
+    labels: object = None
+    assigned_from: np.ndarray | None = None
 
     def top_report(self, k: int) -> str:  # CLI-facing summary
         return (f"k-means: {self.centroids.shape[0]} centroids, "
@@ -991,6 +1002,7 @@ def _run_kmeans_body(config: JobConfig, obs: Obs,
             _save(start_iter + i, c)
 
     want_iter_cb = store is not None or obs.heartbeat is not None
+    fit = None  # the resident fits' KMeansFit
     with obs.phase("iterate"):
         remaining = config.kmeans_iters - start_iter
         if remaining <= 0:
@@ -1068,28 +1080,21 @@ def _run_kmeans_body(config: JobConfig, obs: Obs,
             if n_shards > 1:
                 from map_oxidize_tpu.parallel.kmeans import kmeans_fit_sharded
 
-                timings = {}
-                centroids = kmeans_fit_sharded(
-                    np.asarray(pts, np.float32), centroids,
-                    iters=remaining, num_shards=config.num_shards,
-                    backend=config.backend, on_iter=on_iter,
-                    timings=timings, precision=config.kmeans_precision,
+                fit = kmeans_fit_sharded(
+                    pts, centroids, iters=remaining,
+                    num_shards=config.num_shards, backend=config.backend,
+                    on_iter=on_iter, precision=config.kmeans_precision,
                     obs=obs)
-                for tk, tv in timings.items():
-                    metrics.set(f"time/{tk}", round(tv, 4))
             else:
                 from map_oxidize_tpu.workloads.kmeans import kmeans_fit_device
 
                 from map_oxidize_tpu.runtime.engine import pick_device
 
-                timings: dict = {}
-                centroids = kmeans_fit_device(
-                    np.asarray(pts, np.float32), centroids,
-                    iters=remaining,
+                fit = kmeans_fit_device(
+                    pts, centroids, iters=remaining,
                     device=pick_device(config.backend), on_iter=on_iter,
-                    timings=timings, precision=config.kmeans_precision)
-                for tk, tv in timings.items():
-                    metrics.set(f"time/{tk}", round(tv, 4))
+                    precision=config.kmeans_precision, obs=obs)
+            centroids = fit.centroids
         else:
             from map_oxidize_tpu.workloads.kmeans import KMeansMapper
 
@@ -1135,6 +1140,8 @@ def _run_kmeans_body(config: JobConfig, obs: Obs,
         metrics.set("resumed_iters", start_iter)
     summary, trace = obs.finish(config, "kmeans")
     result = KMeansResult(centroids=centroids, metrics=summary, trace=trace)
+    if fit is not None:
+        result.labels, result.assigned_from = fit.labels, fit.assigned_from
     if config.metrics:
         _log.info("metrics: %s", result.metrics)
     return result

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 from map_oxidize_tpu.api import MapOutput, Reducer
 from map_oxidize_tpu.config import JobConfig
 from map_oxidize_tpu.obs.compile import observed_jit
-from map_oxidize_tpu.obs.trace import NULL_SPAN
+from map_oxidize_tpu.obs.trace import span_of
 from map_oxidize_tpu.ops.hashing import SENTINEL
 from map_oxidize_tpu.ops.segment_reduce import (
     _identity,
@@ -165,8 +165,7 @@ class StreamingEngineBase(abc.ABC):
 
     def _span(self, name: str, **attrs):
         """A span of the job's tracer; the null span with no ``Obs``."""
-        obs = self.obs
-        return obs.tracer.span(name, **attrs) if obs is not None else NULL_SPAN
+        return span_of(self.obs, name, **attrs)
 
     def _round_batch(self, n: int) -> int:
         """Padded size for an ``n``-row slice: next power of two, capped at

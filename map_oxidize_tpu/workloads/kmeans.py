@@ -33,9 +33,12 @@ and streamed by row ranges — the corpus never sits in host RAM).
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import numpy as np
 
 from map_oxidize_tpu.api import Mapper, MapOutput, SumReducer
+from map_oxidize_tpu.obs.trace import span_of
 
 
 def assign_points(points: np.ndarray, centroids: np.ndarray) -> np.ndarray:
@@ -144,74 +147,141 @@ def kmeans_model(points: np.ndarray, centroids: np.ndarray) -> np.ndarray:
     return new
 
 
+class KMeansFit(NamedTuple):
+    """What an HBM-resident fit returns: the final centroids, the
+    centroids its last step assigned against, and that assignment."""
+
+    #: ``c_T``, ``(k, d)`` float32 NumPy
+    centroids: np.ndarray
+    #: ``c_{T-1}``, ``(k, d)`` float32 NumPy: what ``labels`` were
+    #: assigned against
+    assigned_from: np.ndarray
+    #: ``(n,)`` int32 device array, each point's nearest centroid of
+    #: ``assigned_from``; read it (``np.asarray``) to fetch it
+    labels: object
+
+
+def host_points(points, precision: str = "highest") -> np.ndarray:
+    """``points`` (a memory map or an array) as the host array a resident
+    fit puts: float32, or bfloat16 under ``precision="bf16"``.  A float32
+    memory map stays a view: the put reads its pages, while its transfer
+    runs.  (Reading them here first, one value a page, took 0.075 s of a
+    1.6 GB map on a TPU v5e host and left the put as long as before.)"""
+    points = np.asarray(points, np.float32)
+    if precision == "bf16":
+        # store the points bf16 in HBM: every iteration re-reads the whole
+        # array, so half the bytes is half the HBM read; the matmul
+        # operand was cast to bf16 anyway, so the numerics are unchanged.
+        # Bonus: half the host->device transfer.
+        import ml_dtypes
+
+        return points.astype(ml_dtypes.bfloat16)
+    return points
+
+
 def kmeans_fit_device(points, centroids, iters: int = 1, device=None,
-                      on_iter=None, timings: dict | None = None,
-                      precision: str = "highest"):
+                      on_iter=None, precision: str = "highest",
+                      obs=None) -> KMeansFit:
     """HBM-resident k-means: points transfer once, ``iters`` iterations run
     entirely on device (distance matmul + one-hot matmul partial sums — both
-    MXU work).  Returns the final centroids as NumPy.
+    MXU work).  Returns a :class:`KMeansFit`: the final centroids, the
+    centroids the last step assigned against, and that step's assignment
+    (left on the device).  No extra pass over the points: the last step
+    is the one that keeps its assignment.
 
     ``on_iter(i, centroids_np)`` (checkpoint hook): when given, iterations
     step one at a time python-side — points stay in HBM, only the tiny
     ``(k, d)`` centroids cross back per iteration — and the hook sees the
-    state after each.  The per-step jit runs the same compiled body the
+    state after each.  The per-step jit runs the same step the
     ``fori_loop`` path runs, so enabling checkpointing costs one dispatch
     per iteration, not a different computation.
 
-    ``timings`` (when a dict is passed) receives ``transfer_s`` (host->HBM
-    put of the points, the one-time cost iterations amortize) and
-    ``iter_s`` (the full iteration chain, fetch-forced — the compute-bound
-    region an MFU figure should be computed over)."""
-    import time
+    ``obs`` (the job's :class:`~map_oxidize_tpu.obs.Obs`) times three
+    spans: ``kmeans/load`` (:func:`host_points`: ``points``, a memory map
+    or an array, made the host array that is put), ``kmeans/put``
+    (host->HBM put of the points, a float32 map's page reads included, to
+    ``block_until_ready``; also ``time/transfer_s``) and, on the
+    ``fori_loop`` path, ``kmeans/fit`` (the iteration chain, forced by
+    the fetch of the centroids; also ``time/iter_s``).  At HiBench's
+    shapes (d = 20, k = 10) the fit's roofline is set by HBM bandwidth
+    (one read of the points a step), not by the MXU's FLOP/s."""
     import jax
 
-    points = np.asarray(points, np.float32)
+    if iters < 1:
+        raise ValueError(f"k-means fit needs iters >= 1, got {iters}")
     k = np.asarray(centroids, np.float32).shape[0]
-
     if device is None:
         device = jax.devices()[0]
-    if precision == "bf16":
-        # store the points bf16 in HBM: every iteration re-reads the whole
-        # array, so half the bytes is half the HBM read; the matmul
-        # operand was cast to bf16 anyway, so the numerics are
-        # unchanged.  Bonus: half the host->device transfer.
-        import ml_dtypes
-
-        points = points.astype(ml_dtypes.bfloat16)
-    t0 = time.perf_counter()
-    p_dev = jax.device_put(points, device)
-    p_dev.block_until_ready()
-    if timings is not None:
-        timings["transfer_s"] = time.perf_counter() - t0
+    with span_of(obs, "kmeans/load"):
+        points = host_points(points, precision)
+    with span_of(obs, "kmeans/put"):
+        p_dev = jax.device_put(points, device)
+        p_dev.block_until_ready()
     c_dev = jax.device_put(np.asarray(centroids, np.float32), device)
-    t0 = time.perf_counter()
     if on_iter is None:
-        # asarray forces the chain and fetches the (k, d) result
-        out = np.asarray(_kmeans_fit(c_dev, p_dev, k, iters, precision))
-        if timings is not None:
-            timings["iter_s"] = time.perf_counter() - t0
-        return out
+        with span_of(obs, "kmeans/fit"):
+            # the fetch forces the chain and brings back the two (k, d)
+            new, prev, labels = _kmeans_fit(c_dev, p_dev, k, iters,
+                                            precision)
+            new, prev = jax.device_get((new, prev))
+        return KMeansFit(new, prev, labels)
+    # no kmeans/fit span here: this loop interleaves per-iteration
+    # readback and the caller's snapshot I/O, so it is not the device
+    # region a roofline should be computed over
     c = c_dev
     for i in range(iters):
-        c = _kmeans_step(c, p_dev, k, precision)
+        prev = c
+        c, labels = _kmeans_step(c, p_dev, k, precision)
         on_iter(i + 1, np.asarray(c))
-    # no iter_s here: this loop interleaves per-iteration readback and the
-    # caller's snapshot I/O, so it is NOT the compute-bound region the
-    # docstring promises — an MFU computed over it would be wrong
-    return np.asarray(c)
+    return KMeansFit(np.asarray(c), np.asarray(prev), labels)
 
 
-def assign_and_sum(p, c, k: int, precision: str = "highest", w=None):
+def exact_pieces(p):
+    """``p`` (float32) as three bfloat16 arrays that add up to it exactly:
+    its top 8 significant bits, the next 8 and the last 8.  Each cut is a
+    bit mask, which no compiler pass folds away (a round trip through
+    ``astype(bfloat16)`` is folded away on a TPU)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    def top(x):
+        bits = lax.bitcast_convert_type(x, jnp.uint32)
+        return lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                        jnp.float32)
+
+    hi = top(p)
+    rest = p - hi
+    mid = top(rest)
+    return tuple(t.astype(jnp.bfloat16) for t in (hi, mid, rest - mid))
+
+
+def assign_and_sum(p, c, k: int, precision: str = "highest", w=None,
+                   pieces=None):
     """Shared numerics of one k-means iteration (single-device AND sharded
     steps import this, so the two paths cannot drift): distance matmul ->
     argmin assignment -> one-hot partial-sum matmul.  Returns
-    ``(sums (k, d), counts (k,))`` — per-shard partials in the sharded
-    case (``w``: 0/1 row weights so padding never moves a centroid).
+    ``(sums (k, d), counts (k,), cid (n,) int32)`` — per-shard partials
+    and this shard's rows' assignment in the sharded case (``w``: 0/1 row
+    weights so padding never moves a centroid).  A caller that drops
+    ``cid`` pays nothing for it under ``jit``.
 
     ``precision``:
 
-    * ``"highest"`` — f32 operands, ``Precision.HIGHEST`` matmuls (the
-      MXU emulates f32 with multiple bf16 passes; the oracle-parity mode).
+    * ``"highest"`` — f32 operands; the distance matmul at
+      ``Precision.HIGHEST`` (the MXU emulates f32 with bf16 passes), the
+      one-hot sums as three native passes over :func:`exact_pieces` of
+      ``p`` (``pieces``, when the caller hoisted them out of its loop;
+      a streamed chunk is new each step, so its step cuts them): exact
+      products, f32 accumulation.  On a TPU v5e, at n = 2*10^7, XLA's
+      ``HIGHEST`` for that ``(k, n) x (n, d)`` product erred by 1.28e-3 of
+      the largest coordinate, ``HIGH`` by the same, and ``HIGHEST`` as 100
+      batched row blocks by 1.29e-3; one bfloat16 pass over the rounded
+      points erred by 1.28e-5 and the three exact passes by 1.15e-5.  So
+      the error is in the MXU's float32 accumulation of products of
+      full-width (24-bit) operands, not in the products' precision.  (The
+      streamed step's ``HIGHEST`` sums, one dot a ~2*10^5-row chunk, erred
+      by 5.4e-6 after one step; its exact passes by 2.8e-6.)  The
+      oracle-parity mode.
     * ``"bf16"`` — matmul operands cast to bfloat16 with f32 accumulation
       (``preferred_element_type``): ONE native MXU pass per matmul, the
       rate the chip is built for.  One-hot/weight values are 0/1 (exact
@@ -239,23 +309,34 @@ def assign_and_sum(p, c, k: int, precision: str = "highest", w=None):
         raise ValueError(f"unknown kmeans precision {precision!r}")
     # squared-norm term stays f32 in both modes (cheap, no matmul)
     d2 = -2.0 * dot(pm, cm.T) + (c * c).sum(1)
-    cid = jnp.argmin(d2, axis=1)
+    cid = jnp.argmin(d2, axis=1).astype(jnp.int32)
     # one-hot/counts accumulate in f32 ALWAYS: a bf16 count saturates at
     # 256 (8 mantissa bits) — only the matmul operand is cast down
     onehot = jax.nn.one_hot(cid, k, dtype=jnp.float32)       # (n, k)
     if w is not None:
         onehot = onehot * w[:, None]
-    sums = dot(onehot.astype(pm.dtype).T, pm)                # (k, d) on MXU
+    if precision == "bf16":
+        sums = dot(onehot.astype(pm.dtype).T, pm)            # (k, d) on MXU
+    else:
+        # 0/1 one-hot rows are exact in bf16: three exact-product passes,
+        # the smallest piece first
+        hot = onehot.astype(jnp.bfloat16).T
+        hi, mid, lo = exact_pieces(p) if pieces is None else pieces
+        sums = sum(jnp.dot(hot, t, preferred_element_type=jnp.float32)
+                   for t in (lo, mid, hi))
     counts = onehot.sum(0)
-    return sums, counts
+    return sums, counts, cid
 
 
-def _kmeans_step_impl(c, p, k: int, precision: str = "highest"):
+def _kmeans_step_impl(c, p, k: int, precision: str = "highest",
+                      pieces=None):
+    """One Lloyd step: the new centroids (an empty cluster keeps its
+    centroid) and the assignment they were summed over."""
     import jax.numpy as jnp
 
-    sums, counts = assign_and_sum(p, c, k, precision)
+    sums, counts, cid = assign_and_sum(p, c, k, precision, pieces=pieces)
     return jnp.where(counts[:, None] > 0,
-                     sums / jnp.maximum(counts[:, None], 1.0), c)
+                     sums / jnp.maximum(counts[:, None], 1.0), c), cid
 
 
 def _make_jitted():
@@ -277,9 +358,16 @@ def _make_jitted():
     @functools.partial(observed_jit, "kmeans/fit")
     @functools.partial(jax.jit, static_argnums=(2, 3, 4))
     def fit(c, p, k, iters, precision):
-        return lax.fori_loop(
-            0, iters,
-            lambda _, cc: _kmeans_step_impl(cc, p, k, precision), c)
+        # the exact pieces of the points, cut once for every step
+        pieces = exact_pieces(p) if precision == "highest" else None
+        # iters - 1 steps that drop their assignment, then the last step,
+        # which keeps it: (c_T, c_{T-1}, labels) with no extra pass
+        prev = lax.fori_loop(
+            0, iters - 1,
+            lambda _, cc: _kmeans_step_impl(cc, p, k, precision, pieces)[0],
+            c)
+        new, cid = _kmeans_step_impl(prev, p, k, precision, pieces)
+        return new, prev, cid
 
     return step, fit
 

@@ -356,7 +356,7 @@ def test_two_process_kmeans_matches_single_controller(tmp_path):
     from map_oxidize_tpu.workloads.kmeans import kmeans_model
 
     single = kmeans_fit_sharded(pts, pts[:4].copy(), iters=3,
-                                num_shards=8, backend="cpu")
+                                num_shards=8, backend="cpu").centroids
     np.testing.assert_allclose(got[0], single, rtol=2e-6, atol=2e-7)
     want = pts[:4].copy()
     for _ in range(3):
@@ -387,7 +387,7 @@ def test_two_process_kmeans_checkpoint_resume(tmp_path):
     from map_oxidize_tpu.parallel.kmeans import kmeans_fit_sharded
 
     single = kmeans_fit_sharded(pts, pts[:4].copy(), iters=3,
-                                num_shards=8, backend="cpu")
+                                num_shards=8, backend="cpu").centroids
     np.testing.assert_allclose(got[0], single, rtol=2e-6, atol=2e-7)
 
 
@@ -413,5 +413,5 @@ def test_two_process_kmeans_bf16_matches_sharded(tmp_path):
 
     single = kmeans_fit_sharded(pts, pts[:4].copy(), iters=3,
                                 num_shards=8, backend="cpu",
-                                precision="bf16")
+                                precision="bf16").centroids
     np.testing.assert_allclose(got[0], single, rtol=2e-5, atol=2e-5)

@@ -39,14 +39,14 @@ def test_streamed_iteration_matches_oracle(rng):
 
 def test_device_fit_matches_oracle(rng):
     pts, init = _blobs(rng, n=2000, d=4, k=3)
-    got = kmeans_fit_device(pts, init, iters=1)
+    got = kmeans_fit_device(pts, init, iters=1).centroids
     want = kmeans_model(pts, init)
     np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-3)
 
 
 def test_device_fit_multi_iter_matches_repeated_oracle(rng):
     pts, init = _blobs(rng, n=1500, d=4, k=4)
-    got = kmeans_fit_device(pts, init, iters=3)
+    got = kmeans_fit_device(pts, init, iters=3).centroids
     want = init
     for _ in range(3):
         want = kmeans_model(pts, want)
@@ -120,7 +120,8 @@ def test_sharded_fit_matches_oracle(rng):
     from map_oxidize_tpu.parallel.kmeans import kmeans_fit_sharded
 
     pts, init = _blobs(rng, n=2005, d=4, k=3)  # 2005 % 8 != 0: pad rows live
-    got = kmeans_fit_sharded(pts, init, iters=2, num_shards=8, backend="cpu")
+    got = kmeans_fit_sharded(pts, init, iters=2, num_shards=8,
+                             backend="cpu").centroids
     want = init
     for _ in range(2):
         want = kmeans_model(pts, want)
@@ -414,7 +415,7 @@ def test_pallas_fused_kernel_parity(rng):
         for weights in (None, w):
             s1, c1 = fused_assign_sum(p, c, k, prec, w=weights,
                                       interpret=True)
-            s2, c2 = assign_and_sum(p, c, k, prec, w=weights)
+            s2, c2, _ = assign_and_sum(p, c, k, prec, w=weights)
             np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
             np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
                                        rtol=1e-5, atol=1e-4)
