@@ -56,9 +56,11 @@ VIRTUAL_PARTITIONS = 8
 HLL_P = 12
 
 #: hot-key tracker bounds: keep the top ``HOT_KEYS`` for the doc,
-#: tracked through a dict pruned back to ``_HOT_KEEP`` candidates
-#: whenever it grows past ``_HOT_CAP`` (space-bounded heavy hitters;
-#: counts for keys that never leave the candidate set are exact)
+#: tracked through a candidate table (hash-sorted key and count arrays)
+#: pruned back to ``_HOT_KEEP`` candidates whenever a chunk grows it past
+#: ``_HOT_CAP`` (space-bounded heavy hitters; counts for keys that never
+#: leave the candidate set are exact).  Rank is count descending, then
+#: hash ascending — the prune and the top-k both break ties that way
 HOT_KEYS = 10
 _HOT_KEEP = 1024
 _HOT_CAP = 8192
@@ -151,6 +153,19 @@ def pair_digest(keys: np.ndarray, docs: np.ndarray) -> "tuple[int, int]":
     return (int(np.bitwise_xor.reduce(h)), int(h.sum(dtype=_U64)))
 
 
+def _hot_prune(keys: np.ndarray, counts: np.ndarray,
+               keep: int) -> "tuple[np.ndarray, np.ndarray]":
+    """The ``keep`` top-ranked candidates of a hash-sorted table, still
+    hash-sorted: every count above the ``keep``-th largest, then the
+    smallest hashes among the counts equal to it."""
+    n = counts.shape[0]
+    thr = np.partition(counts, n - keep)[n - keep]
+    mask = counts > thr
+    tied = np.flatnonzero(counts == thr)
+    mask[tied[:keep - int(mask.sum())]] = True
+    return keys[mask], counts[mask]
+
+
 def _hll_ranks(hashes: np.ndarray, p: int) -> "tuple[np.ndarray, np.ndarray]":
     """(bucket, rank) per hash — the register-update pair of the
     standard HLL sketch (same frexp trick as
@@ -213,7 +228,11 @@ class DataPlaneAudit:
         self.records_in: "int | None" = None
         #: in-side skew state (fed by map-out records)
         self._regs = np.zeros(self.S << hll_p, np.int32)
-        self._hot: "dict[int, int]" = {}
+        #: hot-key candidates: keys sorted ascending, their row counts
+        self._hot_k = np.zeros(0, _U64)
+        self._hot_c = np.zeros(0, np.int64)
+        #: chunks on which the candidate table outgrew ``_HOT_CAP``
+        self.hot_prunes = 0
         self._hot_resolved: "dict[int, bytes]" = {}
         self.observed_rows: "np.ndarray | None" = None
         self.checks = 0
@@ -231,20 +250,34 @@ class DataPlaneAudit:
                              f"{scope!r} after {st.scope!r}")
         return st
 
-    def _skew(self, keys: np.ndarray, part: np.ndarray,
+    def _skew(self, keys: np.ndarray, h: np.ndarray, part: np.ndarray,
               weights: "np.ndarray | None") -> None:
-        h = mix64(keys)
+        """HLL registers from ``h`` (= ``mix64(keys)``) and the hot-key
+        candidates merged with the chunk's per-key row counts."""
         b, r = _hll_ranks(h, self.p)
         np.maximum.at(self._regs, (part << self.p) + b, r)
         uk, inv = np.unique(keys, return_inverse=True)
         cnt = np.bincount(inv, weights=None if weights is None
-                          else np.asarray(weights, np.float64))
-        hot = self._hot
-        for k, c in zip(uk.tolist(), cnt.tolist()):
-            hot[k] = hot.get(k, 0) + int(c)
-        if len(hot) > _HOT_CAP:
-            keep = sorted(hot.items(), key=lambda kv: -kv[1])[:_HOT_KEEP]
-            self._hot = dict(keep)
+                          else np.asarray(weights, np.float64)
+                          ).astype(np.int64)
+        hk, hc = self._hot_k, self._hot_c
+        pos = np.searchsorted(hk, uk)
+        hit = pos < hk.shape[0]
+        hit[hit] = hk[pos[hit]] == uk[hit]
+        hc[pos[hit]] += cnt[hit]
+        new = ~hit
+        hk = np.insert(hk, pos[new], uk[new])
+        hc = np.insert(hc, pos[new], cnt[new])
+        if hk.shape[0] > _HOT_CAP:
+            hk, hc = _hot_prune(hk, hc, _HOT_KEEP)
+            self.hot_prunes += 1
+        self._hot_k, self._hot_c = hk, hc
+
+    def _hot_top(self, n: int) -> "tuple[np.ndarray, np.ndarray]":
+        """The ``n`` top-ranked candidates (keys, counts), in rank
+        order: count descending, ties by hash ascending."""
+        order = np.lexsort((self._hot_k, -self._hot_c))[:n]
+        return self._hot_k[order], self._hot_c[order]
 
     def _fold(self, name: str, scope: str, keys: np.ndarray,
               values: np.ndarray, skew: bool) -> None:
@@ -259,10 +292,11 @@ class DataPlaneAudit:
         row_b = _U64((keys.nbytes + np.asarray(values).nbytes) // n)
         st.bytes += rows * row_b
         v = np.asarray(values, np.int64).astype(_U64)
+        h = mix64(keys)
         np.add.at(st.vsum, part, v)
-        np.add.at(st.wsum, part, mix64(keys) * v)
+        np.add.at(st.wsum, part, h * v)
         if skew:
-            self._skew(keys, part, values)
+            self._skew(keys, h, part, values)
 
     def _pairs(self, name: str, scope: str, keys: np.ndarray,
                docs: np.ndarray, skew: bool, uniq: bool) -> None:
@@ -284,7 +318,7 @@ class DataPlaneAudit:
             st.uniq += np.bincount(partition_of(uk, self.S),
                                    minlength=self.S).astype(_U64)
         if skew:
-            self._skew(keys, part, None)
+            self._skew(keys, mix64(keys), part, None)
 
     def record_fold_in(self, keys, values) -> None:
         """Map output entering the fold shuffle (pre-exchange, possibly
@@ -351,16 +385,14 @@ class DataPlaneAudit:
                 continue
             for field, vec, op in st.vectors():
                 sections.append((name, field, vec, op))
-        hot = sorted(self._hot.items(), key=lambda kv: -kv[1])
-        hot = hot[:_HOT_KEEP]
-        hot_k = np.zeros(_HOT_KEEP, _U64)
-        hot_c = np.zeros(_HOT_KEEP, _U64)
-        if hot:
-            hot_k[:len(hot)] = np.array([k for k, _ in hot], _U64)
-            hot_c[:len(hot)] = np.array([c for _, c in hot], _U64)
+        keys, counts = self._hot_top(_HOT_KEEP)
+        hot = np.zeros((2, _HOT_KEEP), _U64)  # keys, then their counts
+        hot[0, :keys.shape[0]] = keys
+        hot[1, :counts.shape[0]] = counts
         parts = ([vec for _, _, vec, _ in sections]
-                 + [self._regs.astype(_U64), hot_k, hot_c,
-                    np.array([self.records_in or 0], _U64)])
+                 + [self._regs.astype(_U64), hot.ravel(),
+                    np.array([self.records_in or 0, self.hot_prunes],
+                             _U64)])
         flat = np.concatenate(parts)
         g = np.asarray(allgather(flat), _U64)  # (P, k)
         off = 0
@@ -373,17 +405,16 @@ class DataPlaneAudit:
         regs = g[:, off:off + self._regs.shape[0]]
         off += self._regs.shape[0]
         self._regs = regs.max(axis=0).astype(np.int32)
-        P = g.shape[0]
-        merged: "dict[int, int]" = {}
-        for p_ in range(P):
-            ks = g[p_, off:off + _HOT_KEEP]
-            cs = g[p_, off + _HOT_KEEP:off + 2 * _HOT_KEEP]
-            for k, c in zip(ks.tolist(), cs.tolist()):
-                if c:
-                    merged[k] = merged.get(k, 0) + c
-        self._hot = merged
+        ks = g[:, off:off + _HOT_KEEP].ravel()
+        cs = g[:, off + _HOT_KEEP:off + 2 * _HOT_KEEP].ravel()
+        live = cs != 0
+        uk, inv = np.unique(ks[live], return_inverse=True)
+        self._hot_k = uk
+        self._hot_c = np.zeros(uk.shape[0], np.int64)
+        np.add.at(self._hot_c, inv, cs[live].astype(np.int64))
         off += 2 * _HOT_KEEP
         self.records_in = int(g[:, off].sum(dtype=_U64))
+        self.hot_prunes = int(g[:, off + 1].sum(dtype=_U64))
         self._reduced = True
 
     # --- checks -----------------------------------------------------------
@@ -488,8 +519,7 @@ class DataPlaneAudit:
         distributed caller feeds ``gather_strings`` (identical on every
         process after ``reduce_distributed``, so the collective is
         well-formed)."""
-        return sorted(self._hot, key=self._hot.get, reverse=True)[
-            :self.top_k]
+        return self._hot_top(self.top_k)[0].tolist()
 
     def resolve_hot_keys(self, lookup) -> None:
         """Best-effort hash -> key-bytes resolution for the hot-key
@@ -534,13 +564,12 @@ class DataPlaneAudit:
                 ratio_pp.append(
                     round(int(a.rows[p_]) / u, 3) if u else 0.0)
         hot = []
-        for k in sorted(self._hot, key=self._hot.get, reverse=True)[
-                :self.top_k]:
+        keys, counts = self._hot_top(self.top_k)
+        for k, c in zip(keys.tolist(), counts.tolist()):
             word = self._hot_resolved.get(k)
             if isinstance(word, bytes):
                 word = word.decode("utf-8", "replace")
-            hot.append({"hash": f"{int(k):#018x}", "key": word,
-                        "rows": int(self._hot[k])})
+            hot.append({"hash": f"{k:#018x}", "key": word, "rows": c})
         total_rows = float(rows.sum())
         m = 1 << self.p
         doc = {
@@ -598,8 +627,9 @@ class DataPlaneAudit:
         registry.set("data/conservation_checks", self.checks)
         registry.set("data/conservation_violations",
                      len(self.violations))
-        if self._hot and rows.sum() > 0:
-            top = max(self._hot.values())
+        registry.set("data/hot_prunes", self.hot_prunes)
+        if self._hot_c.shape[0] and rows.sum() > 0:
+            top = int(self._hot_c.max())
             registry.set("data/hot_key_share",
                          round(top / float(rows.sum()), 4))
 

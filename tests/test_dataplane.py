@@ -213,6 +213,166 @@ def test_audit_reduce_distributed_two_halves():
             == half_oracle.tolist())
 
 
+class _DictTrackerAudit:
+    """Oracle for the map-side fold-in: per-partition digests through
+    ``ufunc.at``, HLL registers, and the hot-key candidates as a dict,
+    updated key by key and pruned to the ``_HOT_KEEP`` largest counts by
+    a stable sort whenever it holds more than ``_HOT_CAP`` keys."""
+
+    def __init__(self, S):
+        self.S = S
+        for f in ("rows", "bytes", "vsum", "wsum", "xor", "sum"):
+            setattr(self, f, np.zeros(S, np.uint64))
+        self.regs = np.zeros(S << dpm.HLL_P, np.int32)
+        self.hot: "dict[int, int]" = {}
+        self.prunes = 0
+
+    def _rows(self, keys, row_bytes):
+        part = partition_of(keys, self.S)
+        rows = np.bincount(part, minlength=self.S).astype(np.uint64)
+        self.rows += rows
+        self.bytes += rows * np.uint64(row_bytes)
+        return part
+
+    def _skew(self, keys, part, weights):
+        b, r = dpm._hll_ranks(dpm.mix64(keys), dpm.HLL_P)
+        np.maximum.at(self.regs, (part << dpm.HLL_P) + b, r)
+        uk, inv = np.unique(keys, return_inverse=True)
+        cnt = np.bincount(inv, weights=None if weights is None
+                          else np.asarray(weights, np.float64))
+        for k, c in zip(uk.tolist(), cnt.tolist()):
+            self.hot[k] = self.hot.get(k, 0) + int(c)
+        if len(self.hot) > dpm._HOT_CAP:
+            self.hot = dict(self.top(dpm._HOT_KEEP))
+            self.prunes += 1
+
+    def fold_in(self, keys, values):
+        part = self._rows(keys, (keys.nbytes + values.nbytes)
+                          // keys.shape[0])
+        v = values.astype(np.int64).astype(np.uint64)
+        np.add.at(self.vsum, part, v)
+        np.add.at(self.wsum, part, dpm.mix64(keys) * v)
+        self._skew(keys, part, values)
+
+    def pairs_in(self, keys, docs):
+        part = self._rows(keys, 16)
+        h = dpm.mix64(keys ^ dpm.mix64(docs.view(np.uint64)))
+        np.bitwise_xor.at(self.xor, part, h)
+        np.add.at(self.sum, part, h)
+        self._skew(keys, part, None)
+
+    def top(self, n):
+        return sorted(self.hot.items(), key=lambda kv: -kv[1])[:n]
+
+    def merge(self, other):
+        """Two processes' states after the cross-process reduction:
+        each ships its ``_HOT_KEEP`` top candidates, summed by key."""
+        out = _DictTrackerAudit(self.S)
+        for f in ("rows", "bytes", "vsum", "wsum", "sum"):
+            setattr(out, f, getattr(self, f) + getattr(other, f))
+        out.xor = self.xor ^ other.xor
+        out.regs = np.maximum(self.regs, other.regs)
+        for side in (self, other):
+            for k, c in side.top(dpm._HOT_KEEP):
+                if c:
+                    out.hot[k] = out.hot.get(k, 0) + c
+        out.prunes = self.prunes + other.prunes
+        return out
+
+
+class _Gauges(dict):
+    def set(self, name, value):
+        self[name] = value
+
+
+def _zipf_chunks(n_chunks, rows, pairs, seed):
+    """Zipf (s = 1.1) draws over 60,000 hashed keys, ~20k distinct a
+    chunk so every chunk outgrows ``_HOT_CAP``: fold chunks combined to
+    one row per key with its int32 count, pair chunks left with their
+    keys repeated and a random doc per row."""
+    rng = np.random.default_rng(seed)
+    vocab = rng.integers(1, 1 << 63, 60_000, dtype=np.uint64)
+    cdf = np.cumsum(1.0 / np.arange(1, vocab.shape[0] + 1) ** 1.1)
+    cdf /= cdf[-1]
+    out = []
+    for _ in range(n_chunks):
+        keys = vocab[np.minimum(np.searchsorted(cdf, rng.random(rows)),
+                                vocab.shape[0] - 1)]
+        if pairs:
+            out.append((keys, rng.integers(0, 1 << 40, rows)))
+        else:
+            uk, cnt = np.unique(keys, return_counts=True)
+            perm = rng.permutation(uk.shape[0])
+            out.append((uk[perm], cnt[perm].astype(np.int32)))
+    return out
+
+
+@pytest.mark.parametrize("case", ["fold", "pairs", "two_halves"])
+def test_hot_tracker_matches_dict_oracle(case):
+    """The array candidate table against the dict tracker it replaced,
+    on chunks that each take the prune path: identical digests and
+    registers, the same top-k hot keys and counts, a table bounded by
+    ``_HOT_CAP``, and ``data/hot_prunes`` counting the pruned chunks."""
+    pairs = case == "pairs"
+    chunks = _zipf_chunks(4 if case == "two_halves" else 3, 160_000,
+                          pairs, seed=26)
+    sides = [chunks] if case != "two_halves" else [chunks[:2], chunks[2:]]
+    audits, oracles = [], []
+    for side in sides:
+        a, o = DataPlaneAudit(4), _DictTrackerAudit(4)
+        for keys, vals in side:
+            assert np.unique(keys).shape[0] > dpm._HOT_CAP
+            if pairs:
+                a.record_pairs_in(keys, vals)
+                o.pairs_in(keys, vals)
+            else:
+                a.record_fold_in(keys, vals)
+                o.fold_in(keys, vals)
+            assert a._hot_k.shape[0] <= dpm._HOT_CAP
+        assert a.hot_prunes == o.prunes == len(side)
+        audits.append(a)
+        oracles.append(o)
+    a, o = audits[-1], oracles[-1]
+    if case == "two_halves":
+        flats = []
+        audits[0].reduce_distributed(
+            lambda v: (flats.append(v.copy()), np.stack([v, v]))[1])
+        a.reduce_distributed(lambda v: np.stack([flats[0], v]))
+        o = oracles[0].merge(oracles[1])
+        assert a._hot_k.shape[0] <= dpm._HOT_CAP
+
+    st = a.stages["map_out"]
+    for f in ("rows", "bytes", "vsum", "wsum", "xor", "sum"):
+        assert np.array_equal(getattr(st, f), getattr(o, f)), f
+    assert np.array_equal(a._regs, o.regs)
+    top = o.top(dpm.HOT_KEYS)
+    assert a.hot_hashes() == [k for k, _ in top]
+    assert a.doc()["skew"]["hot_keys"] == [
+        {"hash": f"{k:#018x}", "key": None, "rows": c} for k, c in top]
+    g = _Gauges()
+    a.publish(g)
+    assert g["data/hot_prunes"] == o.prunes == len(chunks)
+
+
+def test_hot_tracker_ties_break_by_hash():
+    """Equal counts rank by hash ascending, in the prune and the top-k,
+    whatever order the rows arrive in."""
+    rng = np.random.default_rng(4)
+    keys = rng.permutation(rng.integers(1, 1 << 63, dpm._HOT_CAP + 1,
+                                        dtype=np.uint64))
+    heavy = keys[-3:]
+    a = DataPlaneAudit(1)
+    a.record_fold_in(keys, np.ones(keys.shape[0], np.int32))
+    assert a.hot_prunes == 1
+    assert np.array_equal(a._hot_k, np.sort(keys)[:dpm._HOT_KEEP])
+    b = DataPlaneAudit(1)
+    b.record_fold_in(heavy, np.full(3, 5, np.int32))
+    b.record_fold_in(keys, np.ones(keys.shape[0], np.int32))
+    assert b.hot_prunes == 1
+    assert b.hot_hashes() == (np.sort(heavy).tolist()
+                              + np.sort(keys[:-3])[:dpm.HOT_KEYS - 3].tolist())
+
+
 # --- ledger gates + SLO rule ---------------------------------------------
 
 
