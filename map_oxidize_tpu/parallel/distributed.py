@@ -761,9 +761,8 @@ def _allgather_i64(vals: np.ndarray, obs=None,
 
 
 def _spilled_invertedindex_result(config: JobConfig, obs, engine,
-                                  dictionary, records: int,
-                                  flag_rounds: int, flag_s: float,
-                                  resumed: int) -> "DistributedResult":
+                                  dictionary, records: int
+                                  ) -> "DistributedResult":
     """Finalize a spilled multi-process inverted index: each process
     drains its private disk buckets into ITS partition's CSR (disjoint
     by owner shard — no process ever materializes the global pair set,
@@ -834,10 +833,8 @@ def _spilled_invertedindex_result(config: JobConfig, obs, engine,
         registry.count("dist/partition_bytes_written", n_bytes)
     registry.set("spilled_pairs", int(engine.spilled_rows))
     del holder  # the doc column was fully consumed by the writer
-    return DistributedResult(
-        counts=None, top=top, n_keys=n_keys, records=records,
-        n_pairs=n_pairs, flag_rounds=flag_rounds, flag_s=flag_s,
-        resumed_chunks=resumed)
+    return DistributedResult(counts=None, top=top, n_keys=n_keys,
+                             records=records, n_pairs=n_pairs)
 
 
 def partition_output_path(output_path: str, proc: int, n_proc: int) -> str:
@@ -882,17 +879,23 @@ def _local_chunks(config: JobConfig, proc: int, n_proc: int, doc_mode: bool,
     _, chunk_bytes = plan_chunks(config.input_path, config.chunk_bytes)
     it = (iter_doc_chunks(config.input_path, chunk_bytes) if doc_mode
           else iter_chunks(config.input_path, chunk_bytes))
-    owned = 0
     off = 0
     for i, chunk in enumerate(it):
-        base = off
-        off += len(chunk)
-        if i % n_proc != proc:
-            continue
-        owned += 1
-        if owned <= skip:
-            continue
-        yield i, chunk, base
+        base, off = off, off + len(chunk)
+        # chunk i is this process's (i // n_proc)-th owned chunk
+        if i % n_proc == proc and i // n_proc >= skip:
+            yield i, chunk, base
+
+
+def _map_local(obs, mapper, chunks, doc_mode: bool = False):
+    """Map :func:`_local_chunks` items under ``dist/map_chunk``: yields
+    ``(global_index, MapOutput, end_offset)``."""
+    for idx, chunk, base in chunks:
+        with obs.tracer.span("dist/map_chunk", index=idx, bytes=len(chunk)):
+            out = (mapper.map_docs(chunk, base) if doc_mode
+                   else mapper.map_chunk(bytes(chunk)))
+            out.ensure_planes()  # no-op except compact keys64 outputs
+        yield idx, out, base + len(chunk)
 
 
 def run_distributed_job(config: JobConfig, workload: str
@@ -959,37 +962,32 @@ def _run_distributed_core(config: JobConfig, workload: str, obs: Obs
 
     from map_oxidize_tpu.ops.hashing import HashDictionary, join_u64
     from map_oxidize_tpu.runtime import resolve_mapper
+    from map_oxidize_tpu.runtime.stream import InputStream
     from map_oxidize_tpu.workloads.bigram import make_bigram
     from map_oxidize_tpu.workloads.wordcount import make_wordcount
 
     registry = obs.registry
     use_native = resolve_mapper(config, workload) == "native"
     doc_mode = workload == "invertedindex"
-    # the planner's shuffle_transport knob resolves through the same
-    # router the engines use (a pin still wins inside resolve_transport:
-    # the knob value IS the pin when one was requested)
-    cap = int(config.collect_max_rows or 0) or (1 << 27)
-    transport = resolve_transport(
-        config, cap, name=obs.knob("shuffle_transport",
-                                   config.shuffle_transport))
-    push_mode = transport == "pipelined"
-    from map_oxidize_tpu.runtime.driver import solved_exchange
+    from map_oxidize_tpu.runtime.driver import (
+        collect_engine_kw,
+        record_transport,
+        solved_exchange,
+        solved_transport,
+    )
 
+    transport = solved_transport(config, obs)
+    push_mode = transport == "pipelined"
     exchange = solved_exchange(config, obs)
-    if workload == "wordcount":
-        mapper, reducer = make_wordcount(config.tokenizer, use_native)
-        engine = DistributedReduceEngine(config, reducer,
-                                         exchange_method=exchange)
-    elif workload == "bigram":
-        mapper, reducer = make_bigram(config.tokenizer, use_native)
+    if workload in ("wordcount", "bigram"):
+        maker = make_wordcount if workload == "wordcount" else make_bigram
+        mapper, reducer = maker(config.tokenizer, use_native)
         engine = DistributedReduceEngine(config, reducer,
                                          exchange_method=exchange)
     elif workload == "invertedindex":
         from map_oxidize_tpu.workloads.inverted_index import (
             make_inverted_index,
         )
-
-        from map_oxidize_tpu.runtime.driver import collect_engine_kw
 
         mapper = make_inverted_index(config.tokenizer, config.use_native)
         engine = DistributedCollectEngine(config, transport=transport,
@@ -998,14 +996,7 @@ def _run_distributed_core(config: JobConfig, workload: str, obs: Obs
     else:
         raise ValueError(f"unknown distributed workload {workload!r}")
     engine.obs = obs
-    if getattr(engine, "transport", None):
-        # the /status shuffle section + ledger entries name the active
-        # transport (collect engines only; fold engines have none)
-        registry.set("shuffle/transport", engine.transport)
-    elif push_mode:
-        # fold engines carry no transport object, but the push cadence
-        # is still theirs — name it for /status and the ledger
-        registry.set("shuffle/transport", "pipelined")
+    record_transport(obs, engine, push_mode)
     P_ = engine.n_proc
     dictionary = HashDictionary()
     # data-plane audit over the GLOBAL shard partition: every process
@@ -1018,82 +1009,34 @@ def _run_distributed_core(config: JobConfig, workload: str, obs: Obs
         conserves=(not doc_mode and reducer.combine == "sum"
                    and getattr(mapper, "conserves_counts", True)))
 
-    # --- per-process checkpoint substore: chunk ownership is part of the
-    # job identity (a resume under a different process count would replay
-    # chunks this process no longer owns)
-    ckpt = None
+    # --- the input stream, with a per-process checkpoint substore: chunk
+    # ownership is part of the job identity (a resume under a different
+    # process count would replay chunks this process no longer owns)
+    stream = InputStream(config, obs, mapper, workload, push=push_mode,
+                         subdir=f"proc_{engine.proc}",
+                         extra={"dist_processes": P_,
+                                "dist_process_id": engine.proc})
     staged_outs: list = []
     staged = 0
-    records = 0
-    resumed = 0
-    if config.checkpoint_dir:
-        import os
-
-        from map_oxidize_tpu.runtime.checkpoint import CheckpointStore
-
-        ckpt = CheckpointStore(
-            os.path.join(config.checkpoint_dir, f"proc_{engine.proc}"),
-            CheckpointStore.job_meta(config, workload, extra={
-                "dist_processes": P_,
-                "dist_process_id": engine.proc,
-            }),
-            registry=registry)
-    vals_dtype = np.uint32 if doc_mode else np.int32
 
     def _produce():
-        """Yield this process's MapOutputs: the checkpointed prefix first
-        (LAZILY — a large resumed prefix streams through the lockstep loop
-        instead of sitting whole in host RAM), then freshly mapped chunks,
-        spilled as they are produced."""
-        nonlocal resumed
-        replayed = 0
-        if ckpt is not None:
-            for _idx, out, _off in ckpt.replay():
-                out.ensure_planes()
-                replayed += 1
-                yield out
-            resumed = replayed
-            if replayed:
-                _log.info("process %d resumed %d checkpointed chunks",
-                          engine.proc, replayed)
-        # the chunk generator starts only now: replay() may stop short of
-        # its saved prefix on a corrupt tail, and those ranges must re-map
-        save_at = replayed
-        for _idx, chunk, base in _local_chunks(config, engine.proc, P_,
-                                               doc_mode, replayed):
-            with obs.tracer.span("dist/map_chunk", index=_idx,
-                                 bytes=len(chunk)):
-                if doc_mode:
-                    out = mapper.map_docs(chunk, base)
-                else:
-                    out = mapper.map_chunk(bytes(chunk))
-                out.ensure_planes()  # no-op except compact keys64 outputs
-            if ckpt is not None:
-                ckpt.save(save_at, out, base + len(chunk))
-                save_at += 1
-            if obs.heartbeat is not None:
-                # processes advance in lockstep, so this process's chunk
-                # end offset tracks GLOBAL progress through the file
-                obs.heartbeat.update(rows=out.records_in,
-                                     bytes_done=base + len(chunk))
-            yield out
+        """The checkpointed prefix (lazily), then this process's fresh
+        chunks, cut only after the replay, which may stop short of its
+        saved prefix on a corrupt tail.  Processes advance in lockstep,
+        so a chunk's end offset tracks GLOBAL progress through the file."""
+        yield from stream.saved()
+        owned = _local_chunks(config, engine.proc, P_, doc_mode,
+                              stream.resume_k)
+        yield from stream.saving(enumerate(
+            (out, end) for _, out, end in _map_local(obs, mapper, owned,
+                                                     doc_mode)))
 
     # --- push cadence: under the pipelined transport the producer runs
     # ahead of the lockstep exchange — chunk k+1 maps on the prefetcher
-    # thread while round k's flag-psum + merge_local occupy this one.
-    # The overlap the critical path's map_shuffle_overlapped what-if
-    # predicted is banked here; pipeline/shuffle_overlap_ratio reports
-    # how much of the feed actually hid behind the exchange.
-    if push_mode:
-        from map_oxidize_tpu.runtime.pipeline import pipelined
-
-        source = pipelined(
-            _produce(),
-            max(2, int(obs.knob("pipeline_depth", config.pipeline_depth))),
-            obs, name="push",
-            ratio_gauge="pipeline/shuffle_overlap_ratio")
-    else:
-        source = _produce()
+    # thread while round k's flag-psum + merge_local occupy this one;
+    # pipeline/shuffle_overlap_ratio reports how much of the feed hid
+    # behind the exchange.
+    source = stream.prefetch(_produce()) if push_mode else _produce()
 
     # --- map-side combiner: sum-combine (min/max alike) partial fold
     # states per push window before they stage.  The data-plane audit
@@ -1111,29 +1054,34 @@ def _run_distributed_core(config: JobConfig, workload: str, obs: Obs
                   and (config.push_combine == "on" or push_mode)
                   and reducer.combine in COMBINABLE)
 
+    def _window() -> MapOutput:
+        """The staged outputs as one window, sum-combined where that folds
+        rows away (an identity window recounts nothing): the native mapper
+        already folds WITHIN a chunk, so the reduction that matters happens
+        here, across the window, just before rows travel.  The audit
+        digested the raw rows at staging — the weighted checksum is
+        sum-combine-invariant, so conservation holds."""
+        outs = staged_outs or [MapOutput(
+            hi=np.empty(0, np.uint32), lo=np.empty(0, np.uint32),
+            values=np.empty((0, 2) if doc_mode else 0,
+                            np.uint32 if doc_mode else np.int32),
+            records_in=0)]
+        win = MapOutput(hi=np.concatenate([o.hi for o in outs]),
+                        lo=np.concatenate([o.lo for o in outs]),
+                        values=np.concatenate([np.asarray(o.values)
+                                               for o in outs]),
+                        records_in=0)
+        if do_combine and len(win):
+            combined, c_in, c_out = combine_map_output(win, reducer.combine)
+            if c_out < c_in:
+                record_push_combine(obs, c_in, c_out)
+                return combined
+        return win
+
     def _pop_block():
         nonlocal staged
-        if staged_outs:
-            hi = np.concatenate([o.hi for o in staged_outs])
-            lo = np.concatenate([o.lo for o in staged_outs])
-            va = np.concatenate([np.asarray(o.values)
-                                 for o in staged_outs])
-        else:
-            hi = np.empty(0, np.uint32)
-            lo = np.empty(0, np.uint32)
-            va = np.empty((0, 2) if doc_mode else 0, vals_dtype)
-        if do_combine and hi.shape[0]:
-            # the push-window combine: the native mapper already folds
-            # WITHIN a chunk, so the reduction that matters happens here,
-            # across the whole staged window, just before rows travel.
-            # The audit digested the raw rows at staging — the weighted
-            # checksum is sum-combine-invariant, so conservation holds.
-            win = MapOutput(hi=hi, lo=lo, values=va, records_in=0)
-            win, c_in, c_out = combine_map_output(win, reducer.combine)
-            if c_out < c_in:  # identity windows recount nothing
-                record_push_combine(obs, c_in, c_out)
-                hi, lo = win.hi, win.lo
-                va = np.asarray(win.values)
+        win = _window()
+        hi, lo, va = win.hi, win.lo, np.asarray(win.values)
         take = min(engine.local_rows, hi.shape[0])
         staged_outs[:] = [MapOutput(
             hi=hi[take:], lo=lo[take:], values=va[take:],
@@ -1153,7 +1101,6 @@ def _run_distributed_core(config: JobConfig, workload: str, obs: Obs
                     exhausted = True
                     break
                 dictionary.update(out.dictionary)
-                records += out.records_in
                 if dp is not None and len(out):
                     rows = _dp.map_output_rows(out, pairs=doc_mode)
                     if rows is not None:
@@ -1172,18 +1119,8 @@ def _run_distributed_core(config: JobConfig, workload: str, obs: Obs
                     # windows leave `staged` untouched and exit the loop,
                     # so re-combining cost amortizes to one sort per
                     # local_rows raw rows.
-                    hi = np.concatenate([o.hi for o in staged_outs])
-                    lo = np.concatenate([o.lo for o in staged_outs])
-                    va = np.concatenate([np.asarray(o.values)
-                                         for o in staged_outs])
-                    win = MapOutput(hi=hi, lo=lo, values=va,
-                                    records_in=0)
-                    win, c_in, c_out = combine_map_output(
-                        win, reducer.combine)
-                    if c_out < c_in:
-                        record_push_combine(obs, c_in, c_out)
-                        staged_outs[:] = [win]
-                        staged = c_out
+                    staged_outs[:] = [_window()]
+                    staged = len(staged_outs[0])
             have = staged > 0
             t0 = _time.perf_counter()
             # round= is the lockstep sequence tag: every process runs
@@ -1215,10 +1152,10 @@ def _run_distributed_core(config: JobConfig, workload: str, obs: Obs
                                  round=flag_rounds - 1):
                 engine.merge_local(*blk)
 
+    records = stream.records_in
     if doc_mode and getattr(engine, "spilled", False):
-        result = _spilled_invertedindex_result(
-            config, obs, engine, dictionary, records=records,
-            flag_rounds=flag_rounds, flag_s=flag_s, resumed=resumed)
+        result = _spilled_invertedindex_result(config, obs, engine,
+                                               dictionary, records)
     elif doc_mode:
         with obs.phase("finalize"):
             keys, docs = engine.finalize()
@@ -1272,9 +1209,7 @@ def _run_distributed_core(config: JobConfig, workload: str, obs: Obs
             registry.count("dist/partition_bytes_written", n_bytes)
         result = DistributedResult(
             counts=None, top=top, n_keys=int(uniq.shape[0]),
-            records=records, n_pairs=int(keys.shape[0]),
-            flag_rounds=flag_rounds, flag_s=flag_s,
-            resumed_chunks=resumed)
+            records=records, n_pairs=int(keys.shape[0]))
     else:
         with obs.phase("finalize"):
             hi, lo, vals, n = engine.finalize()
@@ -1317,12 +1252,11 @@ def _run_distributed_core(config: JobConfig, workload: str, obs: Obs
                     partition_output_path(config.output_path, engine.proc,
                                           P_),
                     ((b, counts[h]) for h, b in names.items()))
-        result = DistributedResult(
-            counts=counts, top=top, n_keys=n, records=records,
-            flag_rounds=flag_rounds, flag_s=flag_s,
-            resumed_chunks=resumed)
-    if ckpt is not None:
-        ckpt.finish(config.keep_intermediates)
+        result = DistributedResult(counts=counts, top=top, n_keys=n,
+                                   records=records)
+    result.flag_rounds, result.flag_s = flag_rounds, flag_s
+    result.resumed_chunks = stream.resume_k
+    stream.finish()
     registry.set("records_in", records)
     registry.set("flag_rounds", flag_rounds)
     registry.set("device_rows_fed",
@@ -1397,14 +1331,9 @@ def _run_remote_staged(config: JobConfig, workload: str, obs: Obs,
         record count of what THIS call mapped."""
         dictionary = HashDictionary()
         records = 0
-        for _idx, chunk, base in _local_chunks(config, owner, n_proc,
-                                               False, 0):
-            if _idx in skip_chunks:
-                continue
-            with obs.tracer.span("dist/map_chunk", index=_idx,
-                                 bytes=len(chunk)):
-                out = mapper.map_chunk(bytes(chunk))
-                out.ensure_planes()
+        chunks = (c for c in _local_chunks(config, owner, n_proc, False)
+                  if c[0] not in skip_chunks)
+        for idx, out, end in _map_local(obs, mapper, chunks):
             dictionary.update(out.dictionary)
             records += out.records_in
             if do_combine and len(out):
@@ -1414,17 +1343,16 @@ def _run_remote_staged(config: JobConfig, workload: str, obs: Obs,
                    else join_u64(out.hi, out.lo))
             va = (np.ones(len(out), np.int64) if out.values is None
                   else np.asarray(out.values))
-            with obs.tracer.span("shuffle/remote_stage", index=_idx,
+            with obs.tracer.span("shuffle/remote_stage", index=idx,
                                  rows=int(k64.shape[0])):
                 # strings BEFORE the chunk commit: a committed chunk's
                 # keys must be resolvable even if this process dies on
                 # the very next instruction (dupes across chunks are
                 # harmless — read_strings last-writes the same bytes)
                 stage.stage_strings(out.dictionary)
-                stage.append_chunk(_idx, k64, va, records=out.records_in)
+                stage.append_chunk(idx, k64, va, records=out.records_in)
             if obs.heartbeat is not None:
-                obs.heartbeat.update(rows=out.records_in,
-                                     bytes_done=base + len(chunk))
+                obs.heartbeat.update(rows=out.records_in, bytes_done=end)
         stage.finish()
         return dictionary, records
 
@@ -1737,16 +1665,13 @@ def _run_distributed_distinct(config: JobConfig, obs: Obs
     # native-unavailable fallback (stream_or_none)
     mapper = DistinctMapper(config.tokenizer, config.use_native, p)
     with obs.phase("map+reduce"):
-        for _i, chunk, base in _local_chunks(config, proc, n_proc, False):
-            with obs.tracer.span("dist/map_chunk", index=_i,
-                                 bytes=len(chunk)):
-                out = mapper.map_chunk(bytes(chunk))
+        chunks = _local_chunks(config, proc, n_proc, False)
+        for _i, out, end in _map_local(obs, mapper, chunks):
             np.maximum.at(registers, np.asarray(out.lo, np.int64),
                           np.asarray(out.values, np.int32))
             records += out.records_in
             if obs.heartbeat is not None:
-                obs.heartbeat.update(rows=out.records_in,
-                                     bytes_done=base + len(chunk))
+                obs.heartbeat.update(rows=out.records_in, bytes_done=end)
     with obs.phase("finalize"):
         import time as _time
 
@@ -1968,8 +1893,3 @@ def _run_distributed_kmeans(config: JobConfig, obs: Obs
                                                           "kmeans")
     return result
 
-
-def run_distributed_wordcount(config: JobConfig, workload: str = "wordcount"):
-    """Back-compat wrapper: ``(counts, top)`` with hash-keyed top pairs."""
-    r = run_distributed_job(config, workload)
-    return r.counts, [(h, c) for h, _w, c in r.top]

@@ -10,7 +10,6 @@ config, metrics, retries, checkpointing hooks, and deterministic output.
 
 from __future__ import annotations
 
-import os
 import time
 from collections.abc import Mapping
 from dataclasses import dataclass, field
@@ -19,13 +18,12 @@ import numpy as np
 
 from map_oxidize_tpu.api import Mapper, Reducer
 from map_oxidize_tpu.config import JobConfig
-from map_oxidize_tpu.io.splitter import iter_chunks, plan_chunks, split_round_robin
 from map_oxidize_tpu.io.writer import format_top_words, write_final_result
 from map_oxidize_tpu.obs import Obs
 from map_oxidize_tpu.ops.hashing import SENTINEL, HashDictionary, join_u64
 from map_oxidize_tpu.runtime.engine import DeviceReduceEngine, StreamingEngineBase
-from map_oxidize_tpu.runtime.executor import run_map_phase
 from map_oxidize_tpu.runtime.pipeline import pipelined
+from map_oxidize_tpu.runtime.stream import InputStream
 from map_oxidize_tpu.shuffle.base import resolve_transport
 from map_oxidize_tpu.utils.logging import get_logger
 
@@ -78,17 +76,6 @@ def collect_engine_kw(config: JobConfig) -> dict:
             if config.collect_max_rows else {})
 
 
-def scan_workers(config: JobConfig) -> int:
-    """Host cores the native mmap scan may fan out over: the configured
-    map workers, capped by the CPUs this process may run on (the scan
-    caps it again by the ranges left)."""
-    try:
-        cpus = len(os.sched_getaffinity(0))
-    except AttributeError:  # no affinity call on this platform
-        cpus = os.cpu_count() or 1
-    return max(1, min(config.num_map_workers, cpus))
-
-
 def solved_transport(config: JobConfig, obs: Obs) -> str:
     """The one route from the planner's ``shuffle_transport`` knob to a
     concrete transport name: the knob value (a pin still wins — the
@@ -99,6 +86,16 @@ def solved_transport(config: JobConfig, obs: Obs) -> str:
     return resolve_transport(config, cap,
                              name=obs.knob("shuffle_transport",
                                            config.shuffle_transport))
+
+
+def record_transport(obs: Obs, engine, push_mode: bool) -> None:
+    """Name the active shuffle transport for /status and the ledger: a
+    collect engine carries one; a fold engine's is ``pipelined`` under
+    the push cadence, else there is none to name."""
+    name = getattr(engine, "transport", None) or (
+        "pipelined" if push_mode else None)
+    if name:
+        obs.registry.set("shuffle/transport", name)
 
 
 def solved_exchange(config: JobConfig, obs: Obs) -> str:
@@ -250,9 +247,6 @@ class LazyCounts(Mapping):
             other = other._materialize()
         return self._materialize() == other
 
-    def __ne__(self, other):
-        return not self.__eq__(other)
-
     def items(self):
         return self._materialize().items()
 
@@ -293,17 +287,6 @@ def _readback(engine: StreamingEngineBase, dictionary: HashDictionary
     return LazyCounts(k64, vals[live], dictionary)
 
 
-def _track_offsets(chunk_iter, start_off: int, offsets: dict, base_idx: int):
-    """Pass chunks through, recording each one's absolute end offset keyed by
-    global chunk index — chunks from ``iter_chunks`` are contiguous consumed
-    byte ranges, so the end offset is the running sum of lengths."""
-    off = start_off
-    for i, mv in enumerate(chunk_iter):
-        off += len(mv)
-        offsets[base_idx + i] = off
-        yield mv
-
-
 def run_wordcount_job(config: JobConfig, mapper: Mapper, reducer: Reducer,
                       workload: str = "wordcount", on_obs=None) -> JobResult:
     """End-to-end word-count-shaped job (scalar sum values, string keys).
@@ -321,18 +304,24 @@ def run_wordcount_job(config: JobConfig, mapper: Mapper, reducer: Reducer,
     bundle to an embedding runtime before the body starts — the resident
     job service uses it to expose live phase/progress on ``/jobs`` and to
     deliver cancel/deadline requests (``Obs.request_cancel``)."""
+    return _recorded(config, workload, on_obs, _run_wordcount_body, mapper,
+                     reducer, workload)
+
+
+def _recorded(config: JobConfig, workload: str, on_obs, body, *args):
+    """Validate ``config``, build the job's ``Obs`` (handed to ``on_obs``
+    before the body starts), and run ``body(config, obs, *args)`` under
+    the flight recorder."""
     config.validate()
     obs = Obs.from_config(config)
     if on_obs is not None:
         on_obs(obs)
     with obs.recording(config, workload):
-        return _run_wordcount_body(config, obs, mapper, reducer, workload)
+        return body(config, obs, *args)
 
 
 def _run_wordcount_body(config: JobConfig, obs: Obs, mapper: Mapper,
                         reducer: Reducer, workload: str) -> JobResult:
-    metrics = obs.registry
-
     # the planner's shuffle_transport knob (Obs.knob seam, same as
     # pipeline_depth) picks the transport; pins still win inside the
     # resolver.  'pipelined' turns on the push cadence: the map pipeline
@@ -347,11 +336,7 @@ def _run_wordcount_body(config: JobConfig, obs: Obs, mapper: Mapper,
                          transport=transport,
                          exchange_method=solved_exchange(config, obs))
     engine.obs = obs
-    if getattr(engine, "transport", None):
-        # collect engines carry a shuffle transport; fold engines don't
-        metrics.set("shuffle/transport", engine.transport)
-    elif push_mode:
-        metrics.set("shuffle/transport", "pipelined")
+    record_transport(obs, engine, push_mode)
     from map_oxidize_tpu.shuffle.pipelined import (
         COMBINABLE,
         combine_map_output,
@@ -382,18 +367,18 @@ def _run_wordcount_body(config: JobConfig, obs: Obs, mapper: Mapper,
         # assign both ways: a mapper reused across jobs must not keep a
         # stale True from an earlier collect-engine run
         mapper.hash_only = hash_only
+    stream = InputStream(config, obs, mapper, workload, push=push_mode,
+                         round_robin=config.num_chunks > 0,
+                         hash_only=hash_only)
     if hash_only:
-        _, rb_chunk = plan_chunks(config.input_path, config.chunk_bytes)
         dictionary = mapper.rescan_dictionary(
-            config.input_path, rb_chunk, early_stop=not config.rescan_full)
+            config.input_path, stream.chunk_bytes,
+            early_stop=not config.rescan_full)
     else:
         dictionary = HashDictionary()
-    records_in = 0
-    n_chunks = 0
     span = obs.tracer.span
 
-    def _ingest(out, next_off: int | None = None) -> None:
-        nonlocal records_in, n_chunks
+    def _ingest(out) -> None:
         with span("feed/dictionary"):
             dictionary.update(out.dictionary)
             if mapper.keys_have_dictionary:
@@ -403,8 +388,6 @@ def _run_wordcount_body(config: JobConfig, obs: Obs, mapper: Mapper,
                 # pending deltas could be duplicate-dominated (see
                 # HashDictionary.upper_bound).
                 engine.hint_total_keys(dictionary.upper_bound())
-        records_in += out.records_in
-        n_chunks += 1
         if dp is not None and len(out):
             from map_oxidize_tpu.obs.dataplane import map_output_rows
 
@@ -420,92 +403,10 @@ def _run_wordcount_body(config: JobConfig, obs: Obs, mapper: Mapper,
             record_push_combine(obs, c_in, c_out)
         with obs.feed_span(rows=len(out)):
             engine.feed(out)
-        if obs.heartbeat is not None:
-            # one update carrying BOTH the rows and the chunk's end offset:
-            # a beat fired here must not read a percent that lags the rows
-            # by one chunk (single-chunk jobs would report 0% throughout)
-            obs.heartbeat.update(rows=out.records_in, bytes_done=next_off)
 
-    # --- replay checkpointed chunks (resume), if any
-    ckpt = None
-    resume_k = 0      # chunks already mapped in a previous run
-    resume_off = 0    # input byte offset where mapping resumes
-    if config.checkpoint_dir:
-        from map_oxidize_tpu.runtime.checkpoint import CheckpointStore
-
-        ckpt = CheckpointStore(
-            config.checkpoint_dir,
-            CheckpointStore.job_meta(config, workload, hash_only=hash_only),
-            registry=metrics)
-        with obs.phase("replay"):
-            for idx, out, next_off in ckpt.replay():
-                _ingest(out)
-                resume_k, resume_off = idx + 1, next_off
-        if resume_k:
-            _log.info("resumed %d checkpointed chunks%s", resume_k,
-                      f" (input offset {resume_off})" if resume_off >= 0
-                      else " (round-robin mode)")
-        resume_off = max(resume_off, 0)  # -1 = round-robin: offsets unused
-
-    # --- split (plan only; chunks stream lazily — contrast main.rs:16/36-51)
-    native_file_iter = None
-    offsets: dict[int, int] = {}  # global chunk idx -> end byte offset
-    with obs.phase("split"):
-        if config.num_chunks > 0:
-            # round-robin compat mode: chunk identity is the index, not a
-            # byte offset — resume skips the first resume_k chunks
-            chunks = split_round_robin(config.input_path,
-                                       config.num_chunks)[resume_k:]
-        else:
-            _, chunk_bytes = plan_chunks(config.input_path, config.chunk_bytes)
-            # native mmap fast path: C++ scans page-cache pages in place
-            # (zero kernel->user copies) and owns the chunk cuts
-            workers = scan_workers(config)
-            if hasattr(mapper, "map_file"):
-                native_file_iter = mapper.map_file(
-                    config.input_path, chunk_bytes, resume_off, workers)
-            if native_file_iter is not None:
-                _log.debug(
-                    "native mmap map path: chunks map in C++ on up to %d "
-                    "host cores, handed on in file order; max_retries does "
-                    "not apply (a map error here is a hash collision or "
-                    "invalid UTF-8, which no retry can fix)", workers)
-            else:
-                chunks = _track_offsets(
-                    iter_chunks(config.input_path, chunk_bytes, resume_off),
-                    resume_off, offsets, resume_k)
-
-    # --- map + reduce, fused streaming phase (main.rs:19-22 were barriered)
-    # The pipeline wrapper runs the host half (C++ scan / python map) in a
-    # bounded prefetch thread so chunk i+1's read+tokenize overlaps chunk
-    # i's engine feed + dispatch below; order is preserved, so the
-    # checkpoint spill and the output are byte-identical to depth 1.
-    with obs.phase("map+reduce"):
-        depth = obs.knob("pipeline_depth", config.pipeline_depth)
-        if push_mode:
-            # the push cadence needs a producer actually running ahead:
-            # depth >= 2, push/* span names for the critpath's push-edge
-            # handoffs, and the overlap-ratio gauge the bench gates on
-            depth = max(2, int(depth))
-        if native_file_iter is not None:
-            it = pipelined(native_file_iter, depth, obs,
-                           name="push" if push_mode else "map",
-                           ratio_gauge=("pipeline/shuffle_overlap_ratio"
-                                        if push_mode else None))
-            for i, (out, next_off) in enumerate(it):
-                _ingest(out, next_off)
-                if ckpt is not None:
-                    ckpt.save(resume_k + i, out, next_off)
-        else:
-            outputs = run_map_phase(
-                chunks, mapper, config.num_map_workers, config.max_retries,
-                pipeline_depth=depth, obs=obs,
-            )
-            for idx, out in outputs:
-                gidx = resume_k + idx
-                _ingest(out, offsets.get(gidx))
-                if ckpt is not None:
-                    ckpt.save(gidx, out, offsets.get(gidx, -1))
+    # --- resume, split (lazily — contrast main.rs:16/36-51), then map +
+    # reduce as one streaming phase (main.rs:19-22 were barriered)
+    stream.run(_ingest)
 
     # --- finalize on device; read back to host strings
     with obs.phase("finalize"):
@@ -520,7 +421,7 @@ def _run_wordcount_body(config: JobConfig, obs: Obs, mapper: Mapper,
     # measurements has no such identity (conserves=False skips it).
     if dp is not None:
         with span("audit/fold_out"):
-            dp.set_records_in(records_in)
+            dp.set_records_in(stream.records_in)
             dp.record_fold_out(counts._k64, counts._vals)
             dp.resolve_hot_keys(dictionary.lookup)
             dp.check_fold()
@@ -529,28 +430,33 @@ def _run_wordcount_body(config: JobConfig, obs: Obs, mapper: Mapper,
           and getattr(mapper, "conserves_counts", True)):
         # legacy global check — the audit's fallback when disabled
         total = counts.total()
-        if records_in and total != records_in:
+        if stream.records_in and total != stream.records_in:
             raise RuntimeError(
-                f"count conservation violated: mapped {records_in} records "
-                f"but reduced counts sum to {total}"
-            )
+                f"count conservation violated: mapped {stream.records_in} "
+                f"records but reduced counts sum to {total}")
 
-    # --- write final result (deterministic, atomic — fixes main.rs:170-182)
+    # --- write (deterministic, atomic — fixes main.rs:170-182)
+    return _finish_job(
+        config, obs, workload, stream,
+        lambda path: write_final_result(path, counts.items()),
+        JobResult(counts=counts, top=top),
+        {"distinct_keys": len(counts), "device_rows_fed": engine.rows_fed})
+
+
+def _finish_job(config: JobConfig, obs: Obs, workload: str,
+                stream: InputStream, write, result, gauges: dict):
+    """Shared tail of the streamed jobs: ``write(output_path)`` under
+    ``phase/write``, drop the spill (the reference always deletes,
+    main.rs:194-202), record the stream's counts and ``gauges``, and
+    close the job's obs into ``result``."""
     with obs.phase("write"):
         if config.output_path:
-            write_final_result(config.output_path, counts.items())
-
-    # --- cleanup (reference: main.rs:194-202 always deletes; here
-    # keep_intermediates preserves the resumable spill)
-    if ckpt is not None:
-        ckpt.finish(config.keep_intermediates)
-
-    metrics.set("records_in", records_in)
-    metrics.set("distinct_keys", len(counts))
-    metrics.set("chunks", n_chunks)
-    metrics.set("device_rows_fed", engine.rows_fed)
-    summary, trace = obs.finish(config, workload)
-    result = JobResult(counts=counts, top=top, metrics=summary, trace=trace)
+            write(config.output_path)
+    stream.finish()
+    for key, value in dict(records_in=stream.records_in,
+                           chunks=stream.chunks, **gauges).items():
+        obs.registry.set(key, value)
+    result.metrics, result.trace = obs.finish(config, workload)
     if config.metrics:
         _log.info("metrics: %s", result.metrics)
     return result
@@ -567,14 +473,9 @@ class InvertedIndexResult:
     trace: list | None = None
 
     def top_report(self, k: int) -> str:
-        if hasattr(self.postings, "top_by_df"):
-            top = self.postings.top_by_df(k)
-        else:
-            top = [(t, len(d)) for t, d in sorted(
-                self.postings.items(), key=lambda kv: (-len(kv[1]), kv[0]))[:k]]
         lines = [f"Top {k} terms by document frequency:"]
         lines += [f"{t.decode('utf-8', 'replace')}: {df} docs"
-                  for t, df in top]
+                  for t, df in self.postings.top_by_df(k)]
         return "\n".join(lines)
 
 
@@ -587,12 +488,8 @@ def run_inverted_index_job(config: JobConfig, on_obs=None
     Output file: one line per term, ``term\\td1 d2 d3...``, terms in byte
     order — deterministic, unlike anything the reference's nondeterministic
     HashMap ordering could produce (main.rs:170-182)."""
-    config.validate()
-    obs = Obs.from_config(config)
-    if on_obs is not None:
-        on_obs(obs)
-    with obs.recording(config, "invertedindex"):
-        return _run_inverted_index_body(config, obs)
+    return _recorded(config, "invertedindex", on_obs,
+                     _run_inverted_index_body)
 
 
 def _run_inverted_index_body(config: JobConfig, obs: Obs
@@ -624,20 +521,14 @@ def _run_inverted_index_body(config: JobConfig, obs: Obs
         engine = CollectEngine(config, transport=transport,
                                **collect_engine_kw(config))
     engine.obs = obs
-    # the active shuffle transport rides /status and the ledger entry
-    metrics.set("shuffle/transport", engine.transport)
+    record_transport(obs, engine, push_mode)
     # data-plane audit: (term, doc) pairs must cross the collect shuffle
     # (and any spill round-trip) as an unchanged multiset
     dp = obs.ensure_dataplane(getattr(engine, "S", 1))
     dictionary = HashDictionary()
-    records_in = 0
-    n_chunks = 0
 
-    def _ingest(out, next_off: int | None = None) -> None:
-        nonlocal records_in, n_chunks
+    def _ingest(out) -> None:
         dictionary.update(out.dictionary)
-        records_in += out.records_in
-        n_chunks += 1
         if dp is not None and len(out):
             from map_oxidize_tpu.obs.dataplane import map_output_rows
 
@@ -645,55 +536,13 @@ def _run_inverted_index_body(config: JobConfig, obs: Obs
                 dp.record_pairs_in(*map_output_rows(out, pairs=True))
         with obs.feed_span(rows=len(out)):
             engine.feed(out)
-        if obs.heartbeat is not None:
-            obs.heartbeat.update(rows=out.records_in, bytes_done=next_off)
 
-    # --- replay checkpointed chunks (resume), if any — the CollectEngine
-    # feed is append-only, so per-chunk spill+replay maps exactly like the
-    # word-count path's (VERDICT r2 weak #5 closed)
-    ckpt = None
-    resume_k = 0
-    resume_off = 0
-    if config.checkpoint_dir:
-        from map_oxidize_tpu.runtime.checkpoint import CheckpointStore
-
-        ckpt = CheckpointStore(
-            config.checkpoint_dir,
-            CheckpointStore.job_meta(config, "invertedindex"),
-            registry=metrics)
-        with obs.phase("replay"):
-            for idx, out, next_off in ckpt.replay():
-                _ingest(out)
-                resume_k, resume_off = idx + 1, next_off
-        if resume_k:
-            _log.info("resumed %d checkpointed chunks (input offset %d)",
-                      resume_k, resume_off)
-
-    with obs.phase("map+collect"):
-        _, chunk_bytes = plan_chunks(config.input_path, config.chunk_bytes)
-        it = mapper.iter_file_docs(config.input_path, chunk_bytes, resume_off)
-        if it is None:
-            from map_oxidize_tpu.io.splitter import iter_doc_chunks
-
-            def _host_iter():
-                off = resume_off
-                for chunk in iter_doc_chunks(config.input_path, chunk_bytes,
-                                             resume_off):
-                    off += len(chunk)
-                    yield mapper.map_docs(chunk, off - len(chunk)), off
-            it = _host_iter()
-        # prefetch: doc-chunk read+tokenize overlaps the collect feed
-        depth = obs.knob("pipeline_depth", config.pipeline_depth)
-        if push_mode:
-            depth = max(2, int(depth))
-        it = pipelined(it, depth, obs,
-                       name="push" if push_mode else "map",
-                       ratio_gauge=("pipeline/shuffle_overlap_ratio"
-                                    if push_mode else None))
-        for i, (out, next_off) in enumerate(it):
-            _ingest(out, next_off)
-            if ckpt is not None:
-                ckpt.save(resume_k + i, out, next_off)
+    # the CollectEngine feed is append-only, so per-chunk spill + replay
+    # resumes it exactly like the word-count path; doc-chunk read +
+    # tokenize runs ahead of the collect feed
+    stream = InputStream(config, obs, mapper, "invertedindex",
+                         push=push_mode)
+    stream.run(_ingest, "map+collect", split_phase=False)
 
     with obs.phase("sort+postings"):
         if getattr(engine, "spilled", False):
@@ -737,38 +586,17 @@ def _run_inverted_index_body(config: JobConfig, obs: Obs
                     dp.record_pairs_out(keys, docs)
             metrics.set("grouped_finalize", csr is not None)
     if dp is not None:
-        dp.set_records_in(records_in)
+        dp.set_records_in(stream.records_in)
         dp.resolve_hot_keys(dictionary.lookup)
         dp.check_pairs()
 
-    return _finish_inverted_index(config, obs, postings, ckpt,
-                                  records_in, n_chunks)
+    from map_oxidize_tpu.io.writer import write_postings
 
-
-def _finish_inverted_index(config, obs, postings, ckpt, records_in,
-                           n_chunks) -> "InvertedIndexResult":
-    """Shared tail of the inverted-index job (in-RAM and spilled CSR
-    paths): write, checkpoint cleanup, metrics, result."""
-    metrics = obs.registry
-    with obs.phase("write"):
-        if config.output_path:
-            from map_oxidize_tpu.io.writer import write_postings
-
-            write_postings(config.output_path, postings)
-
-    if ckpt is not None:
-        ckpt.finish(config.keep_intermediates)
-
-    metrics.set("records_in", records_in)
-    metrics.set("pairs", int(postings.n_pairs))
-    metrics.set("distinct_terms", len(postings))
-    metrics.set("chunks", n_chunks)
-    summary, trace = obs.finish(config, "invertedindex")
-    result = InvertedIndexResult(postings=postings, metrics=summary,
-                                 trace=trace)
-    if config.metrics:
-        _log.info("metrics: %s", result.metrics)
-    return result
+    return _finish_job(
+        config, obs, "invertedindex", stream,
+        lambda path: write_postings(path, postings),
+        InvertedIndexResult(postings=postings),
+        {"pairs": int(postings.n_pairs), "distinct_terms": len(postings)})
 
 
 @dataclass
@@ -873,12 +701,7 @@ def run_kmeans_job(config: JobConfig, centroids: np.ndarray | None = None,
     Input: a ``.npy`` float32 ``(n, d)`` points file, memory-mapped and
     streamed by row ranges.  Initial centroids default to the first
     ``kmeans_k`` points (deterministic)."""
-    config.validate()
-    obs = Obs.from_config(config)
-    if on_obs is not None:
-        on_obs(obs)
-    with obs.recording(config, "kmeans"):
-        return _run_kmeans_body(config, obs, centroids)
+    return _recorded(config, "kmeans", on_obs, _run_kmeans_body, centroids)
 
 
 def _run_kmeans_body(config: JobConfig, obs: Obs,
@@ -1172,12 +995,7 @@ def run_distinct_job(config: JobConfig, on_obs=None) -> DistinctResult:
     there is (fixed tiny key space, no dictionary, no growth), shared
     between the single-chip fold and the sharded mesh engine unchanged.
     See :mod:`map_oxidize_tpu.workloads.distinct` for the formulation."""
-    config.validate()
-    obs = Obs.from_config(config)
-    if on_obs is not None:
-        on_obs(obs)
-    with obs.recording(config, "distinct"):
-        return _run_distinct_body(config, obs)
+    return _recorded(config, "distinct", on_obs, _run_distinct_body)
 
 
 def _run_distinct_body(config: JobConfig, obs: Obs) -> DistinctResult:
@@ -1188,7 +1006,6 @@ def _run_distinct_body(config: JobConfig, obs: Obs) -> DistinctResult:
         hll_estimate,
     )
 
-    metrics = obs.registry
     p = config.hll_precision
     m = 1 << p
     use_native = _rt.resolve_mapper(config, "distinct") == "native"
@@ -1208,13 +1025,7 @@ def _run_distinct_body(config: JobConfig, obs: Obs) -> DistinctResult:
         engine.obs = obs
         engine.hint_total_keys(m)
 
-    records_in = 0
-    n_chunks = 0
-
-    def _ingest(out, next_off: int | None = None) -> None:
-        nonlocal records_in, n_chunks
-        records_in += out.records_in
-        n_chunks += 1
+    def _ingest(out) -> None:
         with obs.feed_span(rows=len(out)):
             if engine is not None:
                 engine.feed(out)
@@ -1223,59 +1034,12 @@ def _run_distinct_body(config: JobConfig, obs: Obs) -> DistinctResult:
                 # fancy-index max is exact (and ~10x ufunc.at)
                 idx = out.lo.astype(np.int64)
                 host_regs[idx] = np.maximum(host_regs[idx], out.values)
-        if obs.heartbeat is not None:
-            obs.heartbeat.update(rows=out.records_in, bytes_done=next_off)
 
-    # --- replay checkpointed chunks (resume), if any — registers are
-    # ordinary (key, value) rows, so the standard per-chunk spill applies
-    ckpt = None
-    resume_k = 0
-    resume_off = 0
-    if config.checkpoint_dir:
-        from map_oxidize_tpu.runtime.checkpoint import CheckpointStore
-
-        ckpt = CheckpointStore(
-            config.checkpoint_dir,
-            CheckpointStore.job_meta(config, "distinct",
-                                     extra={"hll_precision": p}),
-            registry=metrics)
-        with obs.phase("replay"):
-            for idx, out, next_off in ckpt.replay():
-                _ingest(out)
-                resume_k, resume_off = idx + 1, next_off
-
-    with obs.phase("split"):
-        _, chunk_bytes = plan_chunks(config.input_path, config.chunk_bytes)
-        file_iter = mapper.map_file(config.input_path, chunk_bytes,
-                                    resume_off, scan_workers(config))
-        if file_iter is None:
-            offsets: dict[int, int] = {}
-            chunks = _track_offsets(
-                iter_chunks(config.input_path, chunk_bytes, resume_off),
-                resume_off, offsets, resume_k)
-
-    with obs.phase("map+reduce"):
-        if file_iter is not None:
-            it = pipelined(file_iter,
-                           obs.knob("pipeline_depth",
-                                    config.pipeline_depth), obs,
-                           name="map")
-            for i, (out, next_off) in enumerate(it):
-                _ingest(out, next_off)
-                if ckpt is not None:
-                    ckpt.save(resume_k + i, out, next_off)
-        else:
-            for idx, out in run_map_phase(chunks, mapper,
-                                          config.num_map_workers,
-                                          config.max_retries,
-                                          pipeline_depth=obs.knob(
-                                              "pipeline_depth",
-                                              config.pipeline_depth),
-                                          obs=obs):
-                gidx = resume_k + idx
-                _ingest(out, offsets.get(gidx))
-                if ckpt is not None:
-                    ckpt.save(gidx, out, offsets.get(gidx, -1))
+    # registers are ordinary (key, value) rows, so the standard
+    # per-chunk spill and replay apply
+    stream = InputStream(config, obs, mapper, "distinct",
+                         extra={"hll_precision": p})
+    stream.run(_ingest)
 
     with obs.phase("finalize"):
         if engine is not None:
@@ -1290,23 +1054,10 @@ def _run_distinct_body(config: JobConfig, obs: Obs) -> DistinctResult:
             regs = host_regs
         estimate = hll_estimate(regs)
 
-    with obs.phase("write"):
-        if config.output_path:
-            from map_oxidize_tpu.workloads.distinct import (
-                write_distinct_output,
-            )
+    from map_oxidize_tpu.workloads.distinct import write_distinct_output
 
-            write_distinct_output(config.output_path, regs, estimate, p)
-
-    if ckpt is not None:
-        ckpt.finish(config.keep_intermediates)
-
-    metrics.set("records_in", records_in)
-    metrics.set("chunks", n_chunks)
-    metrics.set("registers_filled", int(np.count_nonzero(regs)))
-    summary, trace = obs.finish(config, "distinct")
-    result = DistinctResult(estimate=estimate, registers=regs,
-                            metrics=summary, trace=trace)
-    if config.metrics:
-        _log.info("metrics: %s", result.metrics)
-    return result
+    return _finish_job(
+        config, obs, "distinct", stream,
+        lambda path: write_distinct_output(path, regs, estimate, p),
+        DistinctResult(estimate=estimate, registers=regs),
+        {"registers_filled": int(np.count_nonzero(regs))})

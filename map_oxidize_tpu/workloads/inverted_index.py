@@ -52,11 +52,13 @@ class InvertedIndexMapper(Mapper):
             return self._native.map_docs(chunk, base_doc)
         return self._map_docs_python(chunk, base_doc)
 
-    def iter_file_docs(self, path: str, chunk_bytes: int,
-                       start_offset: int = 0):
-        """Native mmap fast path yielding ``(MapOutput, next_offset)``, or
-        None (driver falls back to the splitter + map_docs with host-tracked
-        offsets)."""
+    def map_file(self, path: str, chunk_bytes: int, start_offset: int = 0,
+                 workers: int = 1):
+        """Native mmap fast path yielding ``(MapOutput, next_offset)`` in
+        file order, or None (the input stream then maps the splitter's doc
+        chunks with ``map_docs``).  The doc scan runs on ONE stream:
+        ``workers`` is taken for the mappers' common signature and not
+        used; the multi-core scan (``OrderedScan``) has no doc variant."""
         if self._native is None:
             return None
         return self._native.iter_file_docs(path, chunk_bytes, start_offset)

@@ -6,6 +6,7 @@ but nothing reads them across runs; here resume is a tested contract
 (VERDICT round 1, item 7)."""
 
 import os
+import threading
 
 import numpy as np
 import pytest
@@ -51,45 +52,98 @@ def _cfg(corpus, out, ckdir, **kw):
     return JobConfig(**base)
 
 
-@pytest.mark.parametrize("use_native", [False, True])
-def test_resume_after_kill_byte_identical(tmp_path, use_native):
+def _map_step(workload):
+    """The mapper method a python-route job maps each chunk with."""
+    from map_oxidize_tpu.workloads.bigram import BigramMapper
+    from map_oxidize_tpu.workloads.distinct import DistinctMapper
+    from map_oxidize_tpu.workloads.inverted_index import InvertedIndexMapper
+
+    return {"wordcount": (WordCountMapper, "map_chunk"),
+            "bigram": (BigramMapper, "map_chunk"),
+            "invertedindex": (InvertedIndexMapper, "map_docs"),
+            "distinct": (DistinctMapper, "map_chunk")}[workload]
+
+
+def _route_kw(route):
+    return dict(mapper=route, use_native=route == "native")
+
+
+# python-native: stopped on the python route, resumed on the native scan.
+# Not for bigram: its native route maps hash-only, another checkpoint
+# identity, so a python spill is discarded there rather than resumed.
+_RESUME_CASES = [
+    (w, r) for w in ("wordcount", "bigram", "invertedindex", "distinct")
+    for r in ("python", "native")] + [
+    (w, "python-native") for w in ("wordcount", "invertedindex", "distinct")]
+
+
+@pytest.mark.parametrize("workload,route", _RESUME_CASES)
+def test_resume_after_kill_byte_identical(tmp_path, monkeypatch, workload,
+                                          route):
+    """A job stopped after chunk k and run again writes the uninterrupted
+    run's output byte for byte: the k saved chunks (a partial prefix of
+    the spill) replay, only the rest maps again, and success removes the
+    spill (reference cleanup semantics).  A checkpoint's identity leaves
+    the route out, so the python splitter and the native scan must cut
+    chunks alike for ``python-native`` to resume."""
+    kill_route, _, resume_route = route.partition("-")
+    resume_route = resume_route or kill_route
+    if "native" in route:
+        from map_oxidize_tpu.native.bindings import load_or_none
+
+        if load_or_none() is None:
+            pytest.skip("native build unavailable")
     corpus = tmp_path / "corpus.txt"
     _make_corpus(corpus)
     ckdir = str(tmp_path / "ck")
-    mapper_mode = "native" if use_native else "python"
+    kw = _route_kw(resume_route)
 
     # reference run: no checkpointing at all
     want_out = tmp_path / "want.txt"
-    run_job(_cfg(corpus, want_out, None, mapper=mapper_mode,
-                 use_native=use_native), "wordcount")
+    n_chunks = run_job(_cfg(corpus, want_out, None, **kw),
+                       workload).metrics["chunks"]
+    assert n_chunks >= 5, n_chunks
 
-    # run 1: dies mid-map.  The python mapper path is used for the kill run
-    # (the native mmap path maps inline in C++ — a per-chunk kill hook needs
-    # map_chunk), so spilled chunks come from the splitter path; the resume
-    # run may then use either path, proving the two agree on chunk cuts.
-    from map_oxidize_tpu.runtime.driver import run_wordcount_job
-    from map_oxidize_tpu.api import SumReducer
+    # run 1: stopped after chunk k — the kill lands on chunk k's save
+    k = 3
+    real_save = CheckpointStore.save
 
-    dying = _DyingMapper(die_after=3, use_native=False)
+    def dying_save(self, idx, out, next_offset):
+        if idx == k:
+            raise KeyboardInterrupt("simulated kill")
+        real_save(self, idx, out, next_offset)
+
     got_out = tmp_path / "got.txt"
-    with pytest.raises(KeyboardInterrupt):
-        run_wordcount_job(_cfg(corpus, got_out, ckdir), dying, SumReducer())
-    saved = [n for n in os.listdir(ckdir) if n.endswith(".npz")]
-    assert len(saved) == 3, saved
+    with monkeypatch.context() as m:
+        m.setattr(CheckpointStore, "save", dying_save)
+        with pytest.raises(KeyboardInterrupt):
+            run_job(_cfg(corpus, got_out, ckdir, **_route_kw(kill_route)),
+                    workload)
+    saved = sorted(n for n in os.listdir(ckdir) if n.endswith(".npz"))
+    assert saved == [f"chunk_{i:06d}.npz" for i in range(k)], saved
 
-    # run 2: resumes — must not re-map the spilled prefix
-    counting = _DyingMapper(die_after=10**9, use_native=use_native)
-    if use_native and counting._native is None:
-        pytest.skip("native build unavailable")
-    res = run_wordcount_job(
-        _cfg(corpus, got_out, ckdir, mapper=mapper_mode,
-             use_native=use_native), counting, SumReducer())
-    total_chunks = res.metrics["chunks"]
-    if not use_native:
-        assert counting.mapped == total_chunks - 3  # prefix was replayed
+    # run 2: resumes — must not re-map the spilled prefix.  Only run 2's
+    # threads count: run 1's prefetch thread, abandoned at the kill, may
+    # still be mapping a chunk it read ahead
+    cls, step = _map_step(workload)
+    real_step = getattr(cls, step)
+    mapped = []
+    run1_threads = set(threading.enumerate())
 
+    def counting_step(self, *a):
+        if threading.current_thread() not in run1_threads:
+            mapped.append(1)
+        return real_step(self, *a)
+
+    monkeypatch.setattr(cls, step, counting_step)
+    res = run_job(_cfg(corpus, got_out, ckdir, **kw), workload)
     assert got_out.read_bytes() == want_out.read_bytes()
-    # success removes the spill by default (reference cleanup semantics)
+    assert res.metrics["chunks"] == n_chunks
+    assert res.metrics["checkpoint/chunks_replayed"] == k
+    assert res.metrics["checkpoint/chunks_saved"] == n_chunks - k
+    # the python route maps the tail through the mapper's own step; the
+    # native scan maps in C++
+    assert len(mapped) == (n_chunks - k if resume_route == "python" else 0)
     assert not os.path.isdir(ckdir)
 
 

@@ -1,8 +1,8 @@
-"""Checkpoint/resume for the paths wired in round 3: the inverted-index
-driver (per-chunk spill + replay, like wordcount) and the device-map
-drivers (engine-state snapshots — map outputs never exist on the host
-there).  Each test proves byte-identical output to an uncheckpointed run
-after a mid-run kill."""
+"""Checkpoint/resume for the device-map drivers (engine-state snapshots —
+map outputs never exist on the host there).  Each test proves
+byte-identical output to an uncheckpointed run after a mid-run kill.
+The per-chunk spill of the streamed workloads (inverted index included)
+is tested in tests/test_checkpoint.py."""
 
 import os
 
@@ -29,40 +29,6 @@ def _cfg(corpus, out, ckdir, **kw):
                 metrics=False, num_map_workers=1, max_retries=0)
     base.update(kw)
     return JobConfig(**base)
-
-
-@pytest.mark.parametrize("use_native", [False, True])
-def test_invertedindex_resume_from_partial_prefix(tmp_path, use_native):
-    """Kill-equivalent: spill everything, truncate the spill to a prefix,
-    resume — output must be byte-identical and only the tail re-mapped."""
-    corpus = tmp_path / "c.txt"
-    _make_corpus(corpus)
-    if use_native:
-        from map_oxidize_tpu.native.bindings import load_or_none
-
-        if load_or_none() is None:
-            pytest.skip("native build unavailable")
-    ckdir = str(tmp_path / "ck")
-
-    want = tmp_path / "want.txt"
-    run_job(_cfg(corpus, want, None, num_shards=1, use_native=use_native),
-            "invertedindex")
-
-    got = tmp_path / "got.txt"
-    run_job(_cfg(corpus, got, ckdir, num_shards=1, use_native=use_native,
-                 keep_intermediates=True), "invertedindex")
-    chunks = sorted(n for n in os.listdir(ckdir) if n.endswith(".npz"))
-    assert len(chunks) >= 4, chunks
-    # simulate the kill: only the first 2 chunks survived
-    for name in chunks[2:]:
-        os.unlink(os.path.join(ckdir, name))
-
-    got2 = tmp_path / "got2.txt"
-    res = run_job(_cfg(corpus, got2, ckdir, num_shards=1,
-                       use_native=use_native), "invertedindex")
-    assert got2.read_bytes() == want.read_bytes()
-    assert res.metrics["chunks"] == len(chunks)  # 2 replayed + tail remapped
-    assert not os.path.isdir(ckdir)  # success cleans up
 
 
 def _dying_capped(monkeypatch, die_after):

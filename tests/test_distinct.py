@@ -113,6 +113,16 @@ def test_distinct_checkpoint_resume(tmp_path):
     assert not os.path.isdir(ck)  # success removes the spill by default
 
 
+def test_distinct_streams_under_num_chunks(tmp_path):
+    """``num_chunks`` (the word count's round-robin split) leaves distinct
+    on its streamed byte-range split: same chunks, same registers."""
+    corpus = _corpus(tmp_path)
+    want = run_job(_cfg(corpus), "distinct")
+    got = run_job(_cfg(corpus, num_chunks=4), "distinct")
+    assert got.metrics["chunks"] == want.metrics["chunks"] > 4
+    np.testing.assert_array_equal(got.registers, want.registers)
+
+
 def test_unions_merge_by_max(tmp_path):
     """Registers from two disjoint corpora merged with np.maximum estimate
     the union — the HLL mergeability property the sharded path relies on."""

@@ -316,7 +316,7 @@ def test_native_kill_resume_byte_identical(tmp_path):
 
 def test_job_reports_scan_workers_and_dedup(tmp_path):
     from map_oxidize_tpu.runtime import run_job
-    from map_oxidize_tpu.runtime.driver import scan_workers
+    from map_oxidize_tpu.runtime.stream import scan_workers
     from map_oxidize_tpu.config import JobConfig
 
     corpus = _corpus(tmp_path / "c.txt", "lines", False)

@@ -315,7 +315,7 @@ _REMOTE_CHILD = textwrap.dedent("""
                     remote_stage_dir=os.path.join(outdir, "stage"),
                     remote_stage_timeout_s=8.0,
                     dist_num_processes=2, dist_process_id=pid,
-                    metrics=False)
+                    metrics=False, progress=True)  # beats per staged chunk
     r = run_distributed_job(cfg, "wordcount")
     json.dump({"counts": {str(k): v for k, v in r.counts.items()},
                "records": r.records},

@@ -255,3 +255,19 @@ for plane in ProfileData.from_file(path).planes:
     for span in ("audit/fold_in", "engine/flush", "phase/map+reduce",
                  "job/finish"):
         assert span in names, span
+
+
+def test_round_robin_needs_no_chunk_bytes(corpus_file, tmp_path):
+    """``num_chunks`` alone configures the round-robin split: with
+    ``chunk_bytes`` 0 the job writes what it writes with a chunk size."""
+    out = tmp_path / "final_result.txt"
+    _, sized = _run(corpus_file, tmp_path, num_chunks=8)
+    want = out.read_bytes()
+    out.unlink()
+    cfg = JobConfig(input_path=corpus_file, output_path=str(out),
+                    chunk_bytes=0, num_chunks=8, batch_size=256,
+                    key_capacity=4096, backend="cpu", use_native=False)
+    mapper, reducer = make_wordcount(cfg.tokenizer, cfg.use_native)
+    res = run_wordcount_job(cfg, mapper, reducer)
+    assert out.read_bytes() == want
+    assert res.metrics["chunks"] == sized.metrics["chunks"] == 8
