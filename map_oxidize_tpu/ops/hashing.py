@@ -230,7 +230,7 @@ class HashDictionary:
         d = self._d
         hashes = np.fromiter(d.keys(), np.uint64, count=len(d))
         toks = list(d.values())
-        lens = np.fromiter((len(t) for t in toks), np.int64, count=len(toks))
+        lens = np.fromiter(map(len, toks), np.int64, count=len(toks))
         blob = (np.frombuffer(b"".join(toks), np.uint8) if toks
                 else np.empty(0, np.uint8))
         return hashes, lens, blob

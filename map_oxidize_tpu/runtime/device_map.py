@@ -25,14 +25,13 @@ import jax.numpy as jnp
 from map_oxidize_tpu.api import SumReducer
 from map_oxidize_tpu.config import JobConfig
 from map_oxidize_tpu.io.splitter import iter_chunks_capped
-from map_oxidize_tpu.io.writer import write_final_result
 from map_oxidize_tpu.ops.device_tokenize import (
     DeviceTokenizer,
     ngram_at,
     pad_chunk,
 )
 from map_oxidize_tpu.ops.hashing import HashDictionary
-from map_oxidize_tpu.runtime.driver import JobResult, _readback
+from map_oxidize_tpu.runtime.driver import JobResult, _readback, write_counts
 from map_oxidize_tpu.runtime.engine import (
     CapacityError,
     DeviceReduceEngine,
@@ -274,7 +273,7 @@ def _run_sharded_device_body(config: JobConfig, obs, ngram: int) -> JobResult:
 
     with obs.phase("write"):
         if config.output_path:
-            write_final_result(config.output_path, counts.items())
+            write_counts(obs, config.output_path, counts)
 
     if ckpt is not None:
         ckpt.finish(config.keep_intermediates)
@@ -428,7 +427,7 @@ def _run_device_wordcount_body(config: JobConfig, obs,
 
     with obs.phase("write"):
         if config.output_path:
-            write_final_result(config.output_path, counts.items())
+            write_counts(obs, config.output_path, counts)
 
     if ckpt is not None:
         ckpt.finish(config.keep_intermediates)

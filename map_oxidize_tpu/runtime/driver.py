@@ -18,7 +18,11 @@ import numpy as np
 
 from map_oxidize_tpu.api import Mapper, Reducer
 from map_oxidize_tpu.config import JobConfig
-from map_oxidize_tpu.io.writer import format_top_words, write_final_result
+from map_oxidize_tpu.io.writer import (
+    CountColumns,
+    format_top_words,
+    write_final_result,
+)
 from map_oxidize_tpu.obs import Obs
 from map_oxidize_tpu.ops.hashing import SENTINEL, HashDictionary, join_u64
 from map_oxidize_tpu.runtime.engine import DeviceReduceEngine, StreamingEngineBase
@@ -219,6 +223,38 @@ class LazyCounts(Mapping):
                                  vals[cand].tolist())]
         pairs.sort(key=lambda kv: (-kv[1], kv[0]))
         return pairs[:k]
+
+    def columns(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """The live keys' words and counts as columns, without building
+        the dict: ``(blob u8, lens i64, counts)``, word ``i`` being
+        ``blob[sum(lens[:i]):][:lens[i]]`` with count ``counts[i]`` (rows
+        in the dictionary's order, not ``_k64``'s).  The keys are matched
+        to the dictionary's columns by one merge of the two sorted hash
+        columns; a key the dictionary lacks raises ``KeyError`` as the
+        per-key lookup does.  Two keys with one word are caught where the
+        words are sorted (:class:`~map_oxidize_tpu.io.writer.CountColumns`)."""
+        prefetch = getattr(self._dict, "prefetch", None)
+        if prefetch is not None:  # hash-only mode: one resolve-all scan
+            prefetch(self._k64)
+        self._dict.materialized()  # flush: the collision check runs first
+        hashes, lens, blob = self._dict.to_arrays()
+        by_hash = np.argsort(hashes)
+        sorted_hashes = hashes[by_hash]
+        by_key = np.argsort(self._k64)
+        keys = self._k64[by_key]
+        at = np.searchsorted(sorted_hashes, keys)
+        found = at < sorted_hashes.size
+        found[found] = sorted_hashes[at[found]] == keys[found]
+        if not found.all():
+            raise KeyError(int(keys[~found][0]))
+        row = by_hash[at]
+        live = np.zeros(hashes.size, bool)
+        live[row] = True
+        counts = np.zeros(hashes.size, self._vals.dtype)
+        counts[row] = self._vals[by_key]
+        if live.all():
+            return blob, lens, counts
+        return blob[np.repeat(live, lens)], lens[live], counts[live]
 
     # --- Mapping protocol (materializes) ----------------------------------
 
@@ -438,9 +474,18 @@ def _run_wordcount_body(config: JobConfig, obs: Obs, mapper: Mapper,
     # --- write (deterministic, atomic — fixes main.rs:170-182)
     return _finish_job(
         config, obs, workload, stream,
-        lambda path: write_final_result(path, counts.items()),
+        lambda path: write_counts(obs, path, counts),
         JobResult(counts=counts, top=top),
         {"distinct_keys": len(counts), "device_rows_fed": engine.rows_fed})
+
+
+def write_counts(obs: Obs, path: str, counts: LazyCounts) -> None:
+    """``final_result.txt`` straight from the readback's columns, with
+    the gauges ``write/rows`` and ``write/tie_rows`` (rows the byte-order
+    sort resolved per row; 0 unless long words tie on their prefix)."""
+    rows = CountColumns(*counts.columns())
+    obs.registry.set("write/rows", write_final_result(path, rows))
+    obs.registry.set("write/tie_rows", rows.tie_rows)
 
 
 def _finish_job(config: JobConfig, obs: Obs, workload: str,
