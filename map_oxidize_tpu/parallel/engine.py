@@ -18,6 +18,8 @@ across chips, which doubles as the *map-side* data parallelism — each shard
 
 from __future__ import annotations
 
+from functools import partial
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -26,7 +28,7 @@ from map_oxidize_tpu.api import Reducer
 from map_oxidize_tpu.config import JobConfig
 from map_oxidize_tpu.ops.segment_reduce import make_accumulator
 from map_oxidize_tpu.parallel.mesh import SHARD_AXIS, make_mesh, sharded
-from map_oxidize_tpu.parallel.shuffle import build_sharded_ops
+from map_oxidize_tpu.parallel.shuffle import build_sharded_ops, mesh_program
 from map_oxidize_tpu.runtime.engine import StreamingEngineBase
 from map_oxidize_tpu.utils.logging import get_logger
 
@@ -36,6 +38,23 @@ _log = get_logger(__name__)
 class ShuffleOverflowError(RuntimeError):
     """A hash bucket exceeded the exchange-buffer capacity; rows would have
     been dropped.  Increase ``bucket_cap`` (or shrink the batch)."""
+
+
+def _build_init(mesh, capacity: int, value_shape: tuple, value_dtype,
+                combine: str):
+    """The empty accumulator of ``capacity`` rows a shard, as a jitted
+    fill with out_shardings: it materializes directly on the mesh (no host
+    buffer over the slow link) and never touches the default device — the
+    mesh may be virtual CPUs while a sick TPU is default.  Closes over
+    values only, never an engine, since the program outlives the job."""
+    from map_oxidize_tpu.obs.compile import observed_jit
+
+    S = mesh.shape[SHARD_AXIS]
+    return observed_jit("shuffle/init_acc", jax.jit(
+        lambda: make_accumulator(capacity * S, value_shape, value_dtype,
+                                 combine, xp=jnp),
+        out_shardings=sharded(mesh),
+    ), tag=(capacity, S, str(value_dtype)))
 
 
 class ShardedReduceEngine(StreamingEngineBase):
@@ -75,18 +94,11 @@ class ShardedReduceEngine(StreamingEngineBase):
             self.mesh, self.combine, bucket_cap, self.batch_per_shard,
             exchange_method=exchange_method,
         )
-        # jitted fill with out_shardings: materializes directly on the mesh
-        # (no host buffer over the slow link) and never touches the default
-        # device — the mesh may be virtual CPUs while a sick TPU is default
-        from map_oxidize_tpu.obs.compile import observed_jit
-
-        init = observed_jit("shuffle/init_acc", jax.jit(
-            lambda: make_accumulator(
-                self.capacity * self.S, self.value_shape, self.value_dtype,
-                self.combine, xp=jnp,
-            ),
-            out_shardings=self._sharding,
-        ), tag=(self.capacity, self.S, str(self.value_dtype)))
+        init = mesh_program(
+            ("init_acc", self.mesh, self.capacity, self.value_shape,
+             self.value_dtype, self.combine),
+            partial(_build_init, self.mesh, self.capacity, self.value_shape,
+                    self.value_dtype, self.combine))
         self._acc = list(init())
         # [S] cumulative dropped-row counter (exchange-bucket drops plus
         # accumulator truncation), threaded through every merge

@@ -30,7 +30,7 @@ no NCCL/MPI analog exists or is needed.
 
 from __future__ import annotations
 
-from functools import lru_cache, partial
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -322,12 +322,109 @@ def _topk_step(acc_hi, acc_lo, acc_vals, k_local: int, k_final: int):
     return jnp.take(gh, sel), jnp.take(gl, sel), jnp.take(gv, sel)
 
 
+#: the mesh programs this process has built, by what defines each (see
+#: :func:`mesh_program`): every engine makes a new ``Mesh`` and closures
+#: of its own, so without this each job traced, lowered and compiled
+#: (or cache-loaded) its merge, growth chain and init again
+_PROGRAMS: dict = {}
+
+
+def mesh_program(key: tuple, build):
+    """The program under ``key``, made by ``build()`` the first time this
+    process asks for it and the same ``jax.jit`` object after that, so a
+    later job's calls hit programs that are already compiled — what the
+    one-chip engine's module-level jits give it.  ``key`` holds
+    everything the program closes over: its kind, the ``Mesh`` (meshes
+    over the same devices and axis compare and hash equal) and its
+    static parameters.  Argument shapes and dtypes need not be in it:
+    they are the jit's own cache key.  Counts ``shuffle/programs_built``
+    or ``shuffle/programs_reused`` into the current job's metrics."""
+    from map_oxidize_tpu.obs.context import current_obs
+
+    fn = _PROGRAMS.get(key)
+    counter = "shuffle/programs_reused"
+    if fn is None:
+        fn = _PROGRAMS.setdefault(key, build())
+        counter = "shuffle/programs_built"
+    obs = current_obs()
+    if obs is not None:
+        obs.registry.count(counter)
+    return fn
+
+
+def _build_merge(mesh, combine: str, bucket_cap: int, exchange_method: str):
+    from map_oxidize_tpu.obs.compile import observed_jit
+
+    S = mesh.shape[SHARD_AXIS]
+    spec = P(SHARD_AXIS)
+    merge = shard_map(
+        partial(_merge_step, num_shards=S, cap=bucket_cap,
+                combine=combine, method=exchange_method),
+        mesh=mesh,
+        in_specs=(spec,) * 7,
+        out_specs=(spec, spec, spec, spec, spec),
+    )
+    # the exchange method is part of the program identity: a chooser
+    # flip IS a new XLA program, and the compile ledger must see it as
+    # one (not a mystery recompile of the same name)
+    return observed_jit("shuffle/merge",
+                        jax.jit(merge, donate_argnums=(0, 1, 2, 3)),
+                        tag=exchange_method)
+
+
+def _build_topk(mesh, k_local: int, k_final: int):
+    from map_oxidize_tpu.obs.compile import observed_jit
+
+    spec = P(SHARD_AXIS)
+    # check_vma=False: the result of top_k over an all_gather IS
+    # replicated, but shard_map's static replication checker can't prove
+    # it through the take/top_k composition.
+    f = shard_map(
+        partial(_topk_step, k_local=k_local, k_final=k_final),
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=(P(), P(), P()),
+        check_vma=False,
+    )
+    return observed_jit("shuffle/top_k", jax.jit(f), tag=(k_local, k_final))
+
+
+def _build_grow(mesh, combine: str, pad_per_shard: int):
+    """Grow each shard's accumulator by ``pad_per_shard`` SENTINEL rows.
+    Growth is per-shard concatenation — a global concat would append all
+    padding after shard S-1's block instead of after each shard's."""
+    from map_oxidize_tpu.obs.compile import observed_jit
+    from map_oxidize_tpu.ops.segment_reduce import make_accumulator
+
+    def _grow(h, l, v):
+        # xp=jnp: this runs inside the jit trace, where the fill must
+        # compile to an on-device broadcast, not a pad-sized constant
+        p_h, p_l, p_v = make_accumulator(
+            pad_per_shard, v.shape[1:], v.dtype, combine, xp=jnp
+        )
+        return (
+            jnp.concatenate([h, p_h]),
+            jnp.concatenate([l, p_l]),
+            jnp.concatenate([v, p_v]),
+        )
+
+    spec = P(SHARD_AXIS)
+    f = shard_map(_grow, mesh=mesh, in_specs=(spec,) * 3,
+                  out_specs=(spec,) * 3)
+    # each growth step IS a new program (new accumulator shape), which
+    # the compile ledger records under one name — a job's first
+    # capacity-growth chain shows up as shuffle/grow compiles with cause
+    # new_input_shape; later jobs on the mesh reuse the chain
+    return observed_jit("shuffle/grow", jax.jit(f, donate_argnums=(0, 1, 2)))
+
+
 def build_sharded_ops(mesh, combine: str = "sum", bucket_cap: int = 0,
                       batch_per_shard: int = 0,
                       exchange_method: str = "all_to_all"):
-    """Compile the sharded merge step and top-k for ``mesh``.
+    """The sharded merge step, top-k and growth for ``mesh``, each built
+    once per process (:func:`mesh_program`).
 
-    Returns ``(merge_fn, topk_fn)``:
+    Returns ``(merge_fn, topk_fn, grow_fn, bucket_cap)``:
 
     * ``merge_fn(acc_hi, acc_lo, acc_vals, ovf, b_hi, b_lo, b_vals)`` — all
       args global row-major arrays sharded on dim 0; returns updated
@@ -336,6 +433,9 @@ def build_sharded_ops(mesh, combine: str = "sum", bucket_cap: int = 0,
       nonzero = rows were dropped, caller must raise).
     * ``topk_fn(acc_hi, acc_lo, acc_vals, k)`` — replicated
       ``(hi_k, lo_k, vals_k)``.
+    * ``grow_fn(acc_hi, acc_lo, acc_vals, pad_per_shard)`` — the
+      accumulator with ``pad_per_shard`` SENTINEL rows added to each
+      shard's block (donated).
 
     ``bucket_cap`` = slots per destination shard in the exchange buffer.  0
     derives ``2*ceil(B/S) + 16``: expected load is B/S, doubled for hash
@@ -348,69 +448,22 @@ def build_sharded_ops(mesh, combine: str = "sum", bucket_cap: int = 0,
             raise ValueError("need bucket_cap or batch_per_shard")
         bucket_cap = min(batch_per_shard, 2 * (-(-batch_per_shard // S)) + 16)
 
-    spec = P(SHARD_AXIS)
-    merge = shard_map(
-        partial(_merge_step, num_shards=S, cap=bucket_cap,
-                combine=combine, method=exchange_method),
-        mesh=mesh,
-        in_specs=(spec,) * 7,
-        out_specs=(spec, spec, spec, spec, spec),
-    )
-    from map_oxidize_tpu.obs.compile import observed_jit
-
-    # the exchange method is part of the program identity: a chooser
-    # flip IS a new XLA program, and the compile ledger must see it as
-    # one (not a mystery recompile of the same name)
-    merge = observed_jit("shuffle/merge",
-                         jax.jit(merge, donate_argnums=(0, 1, 2, 3)),
-                         tag=exchange_method)
-
-    @lru_cache(maxsize=None)
-    def _topk_compiled(k_local: int, k_final: int):
-        # check_vma=False: the result of top_k over an all_gather IS
-        # replicated, but shard_map's static replication checker can't prove
-        # it through the take/top_k composition.
-        f = shard_map(
-            partial(_topk_step, k_local=k_local, k_final=k_final),
-            mesh=mesh,
-            in_specs=(spec, spec, spec),
-            out_specs=(P(), P(), P()),
-            check_vma=False,
-        )
-        return observed_jit("shuffle/top_k", jax.jit(f),
-                            tag=(k_local, k_final))
+    merge = mesh_program(
+        ("merge", mesh, combine, bucket_cap, exchange_method),
+        partial(_build_merge, mesh, combine, bucket_cap, exchange_method))
 
     def grow_fn(acc_hi, acc_lo, acc_vals, pad_per_shard: int):
-        """Grow each shard's accumulator by ``pad_per_shard`` SENTINEL rows.
-        Growth is per-shard concatenation — a global concat would append all
-        padding after shard S-1's block instead of after each shard's."""
-        from map_oxidize_tpu.ops.segment_reduce import make_accumulator
-
-        def _grow(h, l, v):
-            # xp=jnp: this runs inside the jit trace, where the fill must
-            # compile to an on-device broadcast, not a pad-sized constant
-            p_h, p_l, p_v = make_accumulator(
-                pad_per_shard, v.shape[1:], v.dtype, combine, xp=jnp
-            )
-            return (
-                jnp.concatenate([h, p_h]),
-                jnp.concatenate([l, p_l]),
-                jnp.concatenate([v, p_v]),
-            )
-
-        f = shard_map(_grow, mesh=mesh, in_specs=(spec,) * 3,
-                          out_specs=(spec,) * 3)
-        # a fresh jit per growth step: each growth genuinely IS a new
-        # program (new accumulator shape), which the compile ledger
-        # records under one name — capacity-growth compile chains show up
-        # as shuffle/grow compiles with cause new_input_shape
-        return observed_jit("shuffle/grow", jax.jit(
-            f, donate_argnums=(0, 1, 2)))(acc_hi, acc_lo, acc_vals)
+        grow = mesh_program(("grow", mesh, combine, pad_per_shard),
+                            partial(_build_grow, mesh, combine,
+                                    pad_per_shard))
+        return grow(acc_hi, acc_lo, acc_vals)
 
     def topk_fn(acc_hi, acc_lo, acc_vals, k: int):
         cap_per_shard = acc_hi.shape[0] // S
         k_local = min(k, cap_per_shard)
         k_final = min(k, S * k_local)
-        return _topk_compiled(k_local, k_final)(acc_hi, acc_lo, acc_vals)
+        topk = mesh_program(("top_k", mesh, k_local, k_final),
+                            partial(_build_topk, mesh, k_local, k_final))
+        return topk(acc_hi, acc_lo, acc_vals)
 
     return merge, topk_fn, grow_fn, bucket_cap

@@ -133,9 +133,14 @@ def test_job_delta_baseline_windows():
 @pytest.fixture(scope="module")
 def sharded_wordcount(tmp_path_factory):
     from map_oxidize_tpu.config import JobConfig
+    from map_oxidize_tpu.parallel import shuffle
     from map_oxidize_tpu.runtime.driver import run_wordcount_job
     from map_oxidize_tpu.workloads.wordcount import make_wordcount
 
+    # mesh programs live for the process: drop those an earlier test built
+    # on this mesh, so this job builds and compiles its own and the counts
+    # below stay exact
+    shuffle._PROGRAMS.clear()
     tmp = tmp_path_factory.mktemp("xprof")
     corpus = tmp / "c.txt"
     # the mapper combines per chunk (6 distinct words -> 6 rows/chunk), so
